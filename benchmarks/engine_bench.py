@@ -496,6 +496,8 @@ def collective_calibration(print_fn=print, *, ledger_path: str | None = None
 
     Subprocess because ``xla_force_host_platform_device_count`` must be
     set before jax initializes — this process has already done so.  The
+    child is a host-CPU grid by design and runs with ``JAX_PLATFORMS=cpu``:
+    on an accelerator host the chip belongs to this process.  The
     /tmp ledger persists, so after the first nightly run this is
     resume + fit.  Skips (empty dict) instead of failing when the
     subprocess or the fit can't run — same degraded contract as
@@ -515,6 +517,7 @@ def collective_calibration(print_fn=print, *, ledger_path: str | None = None
         print("CELLS", out["measured"], out["failed"], out["remaining"])
     """)
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(os.path.dirname(__file__), "..", "src")]

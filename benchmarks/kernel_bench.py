@@ -31,7 +31,6 @@ from repro.kernels.paged_decode import tiling as pd_tiling
 from repro.kernels.serve_kv import tiling as kv_tiling
 from repro.kernels.ssm_scan import tiling as ssm_tiling
 from repro.kernels.ssm_scan.ref import ssd_ref
-from repro.launch.mesh import TPU_V5E
 
 from .common import csv_line
 
@@ -71,10 +70,11 @@ def _tuned_rows(tuner: KernelTuner, kernel: str, shape: dict, print_fn) -> dict:
 
 
 def run(print_fn=print) -> dict:
-    peak, bw = TPU_V5E["peak_flops_bf16"], TPU_V5E["hbm_bw"]
+    v5e = get_device("tpu_v5e")
+    peak, bw = v5e.peak_flops, v5e.hbm_bw
     if os.path.exists(TUNING_CACHE):
         os.unlink(TUNING_CACHE)
-    tuner = KernelTuner(device=get_device("tpu_v5e"), cache=TUNING_CACHE,
+    tuner = KernelTuner(device=v5e, cache=TUNING_CACHE,
                         measure=False)
     results: dict = {}
     rng = np.random.default_rng(0)
@@ -149,8 +149,7 @@ def run(print_fn=print) -> dict:
     results["paged_decode"] = _tuned_rows(tuner, "paged_decode", pd_shape,
                                           print_fn)
     from repro.kernels.autotune import roofline_seconds
-    gather_us = roofline_seconds(pd_tiling.gather_cost(pd_shape),
-                                 get_device("tpu_v5e")) * 1e6
+    gather_us = roofline_seconds(pd_tiling.gather_cost(pd_shape), v5e) * 1e6
     results["paged_decode"]["gather_us"] = gather_us
     results["paged_decode"]["vs_gather"] = (
         gather_us / max(results["paged_decode"]["tuned_us"], 1e-12))

@@ -23,6 +23,9 @@ def main() -> None:
                     help="skip benches that may profile new configs")
     args = ap.parse_args()
 
+    from repro.core.cache_dirs import use_compile_cache
+
+    use_compile_cache()
     from . import (dnnmem_comparison, engine_bench, fig3_same_network,
                    fig4_basis, kernel_bench, roofline_table,
                    strategy_variation, table2_case_study, trainset_sweep)

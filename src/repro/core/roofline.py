@@ -22,7 +22,6 @@ from dataclasses import asdict, dataclass
 from dataclasses import field as dataclasses_field
 
 from repro.core.hlo_cost import parse_hlo_cost
-from repro.launch.mesh import TPU_V5E
 
 __all__ = ["RooflineReport", "roofline_from_compiled"]
 
@@ -107,11 +106,15 @@ def roofline_from_compiled(
     mesh_desc: str,
     n_devices: int,
     model_flops_total: float,
-    hw: dict = TPU_V5E,
+    hw=None,
     compile_s: float = 0.0,
 ) -> RooflineReport:
-    # ``hw`` is a constants dict or an engine DeviceSpec (duck-typed so the
-    # core layer needs no engine import).
+    # ``hw`` is a constants dict or an engine DeviceSpec (duck-typed);
+    # None is the registry's ``tpu_v5e`` spec.
+    if hw is None:
+        from repro.engine.devices import get_device
+
+        hw = get_device("tpu_v5e")
     if hasattr(hw, "hw_table"):
         hw = hw.hw_table()
     # Trip-count-aware parse of the optimized HLO (XLA's cost_analysis counts

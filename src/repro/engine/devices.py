@@ -16,8 +16,8 @@ Specs come from three places:
 * :func:`repro.engine.calibrate.calibrate` — constants fitted against
   :class:`~repro.engine.backends.ProfilerBackend` ground truth,
   ``calibrated=True``;
-* :func:`from_jax_device` — auto-derived from a live ``jax.devices()``
-  entry (platform heuristics, still uncalibrated).
+* :func:`from_jax_device` — the registry spec for a live ``jax.devices()``
+  entry, looked up by its ``device_kind`` (still uncalibrated).
 
 ``fingerprint()`` hashes every constant that affects a prediction; the
 engine salts estimate-cache keys with it so calibrated and uncalibrated
@@ -227,8 +227,10 @@ class DeviceSpec:
 # ---------------------------------------------------------------------------
 # Registry.  host_cpu carries the constants that used to live as the
 # HOST_CPU literal in engine/backends.py; tx2_like approximates the paper's
-# Jetson TX2 (§6: 256-core Pascal, 8 GB unified LPDDR4); tpu_v5e mirrors
-# launch/mesh.TPU_V5E for the LM/HLO path.
+# Jetson TX2 (§6: 256-core Pascal, 8 GB unified LPDDR4); tpu_v5e is the
+# one table of TPU v5e peaks (Google Cloud "TPU v5e" documentation: 197
+# TFLOP/s bf16, 16 GB HBM at 819 GB/s) used by the LM/HLO path, the
+# roofline report and the kernel tuner.
 # ---------------------------------------------------------------------------
 
 DEVICE_REGISTRY: dict[str, DeviceSpec] = {}
@@ -324,17 +326,34 @@ def resolve_device(device, default: str = "host_cpu") -> DeviceSpec:
     raise TypeError(f"cannot resolve a DeviceSpec from {device!r}")
 
 
+# jax ``device_kind`` → registry spec.  JAX names a v5e chip either way.
+JAX_DEVICE_KINDS = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5e": "tpu_v5e",
+}
+
+
 def from_jax_device(dev=None) -> DeviceSpec:
     """Derive an (uncalibrated) spec from a live jax device: the registry
-    template for its platform, named after the device kind, with the memory
-    capacity read from ``memory_stats()`` when the runtime exposes it."""
+    spec for its ``device_kind`` (the CPU platform maps to ``host_cpu``),
+    named after the kind, with the memory capacity read from
+    ``memory_stats()`` when the runtime exposes it.  A device with no
+    registry entry raises: its peaks are unknown, and borrowing another
+    device's would mis-price every estimate made with them."""
     if dev is None:
         import jax
 
         dev = jax.devices()[0]
-    platform = getattr(dev, "platform", "cpu")
-    base = get_device({"tpu": "tpu_v5e", "gpu": "tx2_like"}.get(platform, "host_cpu"))
-    kind = getattr(dev, "device_kind", platform) or platform
+    platform = dev.platform
+    kind = dev.device_kind
+    if platform == "cpu":
+        base = get_device("host_cpu")
+    elif kind in JAX_DEVICE_KINDS:
+        base = get_device(JAX_DEVICE_KINDS[kind])
+    else:
+        raise KeyError(
+            f"no device spec for jax device kind {kind!r} (platform "
+            f"{platform!r}); known kinds: {sorted(JAX_DEVICE_KINDS)}")
     name = "jax_" + "".join(c if c.isalnum() else "_" for c in str(kind).lower())
     hbm = base.hbm_bytes
     try:

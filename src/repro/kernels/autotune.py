@@ -295,12 +295,9 @@ class KernelTuner:
     def _should_measure(self) -> bool:
         if self.measure is not None:
             return self.measure
-        try:
-            import jax
+        import jax
 
-            return jax.default_backend() == "tpu"
-        except Exception:
-            return False
+        return jax.default_backend() == "tpu"
 
     # -- keys --------------------------------------------------------------
 
@@ -416,11 +413,9 @@ def autotune_enabled() -> bool:
 
 
 def _default_cache_path() -> str:
-    return os.environ.get(
-        "REPRO_TUNING_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "kernel_tuning.json"),
-    )
+    from repro.core.cache_dirs import TUNING_CACHE_PATH
+
+    return os.environ.get("REPRO_TUNING_CACHE", TUNING_CACHE_PATH)
 
 
 def get_tuner() -> KernelTuner:
@@ -438,12 +433,15 @@ def set_tuner(tuner: KernelTuner | None) -> None:
 
 
 def tuned_config(kernel: str, shape: dict, default: dict | None = None) -> dict:
-    """Best-effort tuned config for implicit call sites: returns ``default``
-    (or {}) when autotuning is disabled or the lookup fails — a model
-    forward must never die because a cache directory is read-only."""
+    """Tuned config for implicit call sites: returns ``default`` (or {})
+    when autotuning is disabled or the tuning cache cannot be read or
+    written — a model forward must not die because a cache directory is
+    read-only.  Every other error propagates: a candidate kernel that the
+    chip's compiler refuses while it is being timed fails the run instead
+    of quietly becoming the default block config."""
     if not autotune_enabled():
         return dict(default or {})
     try:
         return get_tuner().tune(kernel, shape)
-    except Exception:
+    except (OSError, json.JSONDecodeError):
         return dict(default or {})

@@ -7,9 +7,17 @@ elision) and the blocks trade sequenced-step count and MXU fill against
 the q/accumulator/score-tile working set:
 
 * ``block_q`` — programs per (b, h); bigger blocks amortise grid steps
-  and fill MXU rows, at (bq·Dh)·(bpe + 8) + 4·bq·bk VMEM.
+  and fill MXU rows, at the cost of ``block_q``-row f32 values.
 * ``block_k`` — inner ``fori_loop`` trips; bigger chunks cut loop
   overhead and fill MXU columns, at 4·bq·bk f32 score-tile bytes.
+
+The VMEM model is an upper bound fitted to what the v5e compiler
+allocates (scoped VMEM reported for bq, bk ∈ 128…2048, Sk ∈ {512,
+2048}, Dh 128): every block double-buffered by the pipeline, plus a
+stack of six f32 ``(bq, max(Dh, 128))`` values (q, the accumulator
+carry, and the lane-padded running stats and their broadcasts) and one
+f32 ``(bq, bk)`` score tile.  A config over budget is refused by the
+chip's compiler, so the tuner must never time it.
 """
 
 from __future__ import annotations
@@ -61,10 +69,9 @@ def cost(shape: dict, config: dict) -> KernelCost:
     # q/o once per program = once total; K/V once per kv head (consecutive
     # q-heads sharing a kv head revisit the same block — no re-fetch)
     hbm = bpe * (2.0 * B * H * Sq * Dh + 2.0 * B * Hkv * Sk * Dh)
-    vmem = (bpe * (bq * Dh + 2 * Sk * Dh + bq * Dh)   # q, K/V, o blocks
-            + 4.0 * bq * Dh                            # f32 accumulator
-            + 4.0 * bq * bk                            # f32 score/prob tile
-            + 4.0 * 3 * bq)                            # m/l running stats
+    vmem = (2.0 * bpe * (2 * bq * Dh + 2 * Sk * Dh)  # q, o, K/V, x2 buffers
+            + 4.0 * 6 * bq * max(Dh, 128)            # f32 row values
+            + 4.0 * bq * bk)                         # f32 score/prob tile
     n_programs = B * H * (Sq // bq)
     return KernelCost(
         op="flash_attention", op_class="matmul", origin="kernel",

@@ -7,6 +7,14 @@ The block table and per-row cache lengths ride in scalar-prefetch SMEM
 one KV head straight from HBM; the gathered ``(B, NB·bs)`` logical view
 the XLA fallback materialises never exists.
 
+The pool is head-major, ``(P, Hkv, bs, Dh)``, so one head's block is a
+``(bs, Dh)`` tile whose last two dims are whole array dims.  Mosaic
+refuses the token-major ``(P, bs, Hkv, Dh)`` layout: a one-head block
+there has a second-minor block dim of 1 against Hkv, and indexing the
+head inside an all-heads block is an unaligned sublane slice.  The
+running (m, l) stats are written out padded to a full 128-lane row for
+the same reason.
+
 Early exit is block-granular: row ``b`` owns ``cache_len[b]//bs + 1``
 live blocks, and the index map *clamps* dead steps to the last live
 block — consecutive dead steps fetch the same block, which Pallas's
@@ -43,7 +51,7 @@ from repro.kernels.autotune import largest_dividing_block
 __all__ = ["paged_decode_kernel", "combine_splits"]
 
 NEG_INF = -1e30
-_STAT_LANES = 128  # f32 stat scratch padded to one full lane register
+_STAT_LANES = 128  # f32 stats padded to one full lane row
 
 
 def _decode_body(bt_ref, cl_ref, q_ref, k_ref, v_ref,
@@ -71,9 +79,10 @@ def _decode_body(bt_ref, cl_ref, q_ref, k_ref, v_ref,
         q = q_ref[0, 0].astype(jnp.float32) * scale             # (rep, dh)
 
         def chunk(c, _):
-            k = k_ref[0, pl.dslice(c * block_kv, block_kv), 0, :].astype(
+            start = pl.multiple_of(c * block_kv, block_kv)
+            k = k_ref[0, 0, pl.dslice(start, block_kv), :].astype(
                 jnp.float32)                                    # (bkv, dh)
-            v = v_ref[0, pl.dslice(c * block_kv, block_kv), 0, :].astype(
+            v = v_ref[0, 0, pl.dslice(start, block_kv), :].astype(
                 jnp.float32)
             sc = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -99,15 +108,16 @@ def _decode_body(bt_ref, cl_ref, q_ref, k_ref, v_ref,
 
     # Unnormalised partials flush when the split's output block rotates.
     o_ref[0, 0, 0] = acc_scr[...]
-    m_ref[0, 0, 0] = m_scr[:, 0]
-    l_ref[0, 0, 0] = l_scr[:, 0]
+    m_ref[0, 0, 0] = m_scr[...]
+    l_ref[0, 0, 0] = l_scr[...]
 
 
 def combine_splits(acc, m, l, out_dtype):
-    """Merge per-split partials: acc/m/l are (B, Hkv, n_splits, rep[, Dh])
-    f32 → (B, H, Dh).  Dead splits carry (acc=0, m=NEG_INF, l=0) and
-    vanish under the global-max renormalisation (NEG_INF is finite, so
-    the exp underflows to exactly 0 instead of producing NaN)."""
+    """Merge per-split partials: acc is (B, Hkv, n_splits, rep, Dh) and
+    m/l are (B, Hkv, n_splits, rep), all f32 → (B, H, Dh).  Dead splits
+    carry (acc=0, m=NEG_INF, l=0) and vanish under the global-max
+    renormalisation (NEG_INF is finite, so the exp underflows to exactly
+    0 instead of producing NaN)."""
     B, Hkv, n_splits, rep, Dh = acc.shape
     m_g = jnp.max(m, axis=2, keepdims=True)                 # (B, Hkv, 1, rep)
     w = jnp.exp(m - m_g)                                    # (B, Hkv, s, rep)
@@ -122,11 +132,11 @@ def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
                         block_kv: int | None = None,
                         n_splits: int = 1,
                         interpret: bool = False):
-    """q: (B, H, Dh); k/v_pool: (P, bs, Hkv, Dh); block_table: (B, NB);
+    """q: (B, H, Dh); k/v_pool: (P, Hkv, bs, Dh); block_table: (B, NB);
     cache_len: (B,) → (B, H, Dh).  Attends positions ``<= cache_len[b]``.
     """
     B, H, Dh = q.shape
-    bs, Hkv = k_pool.shape[1], k_pool.shape[2]
+    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
     NB = block_table.shape[1]
     assert H % Hkv == 0, (H, Hkv)
     rep = H // Hkv
@@ -141,7 +151,10 @@ def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
         i = s * npb + j
         n_live = cl_ref[b] // bs + 1
         live = jnp.minimum(i, n_live - 1)          # clamp dead steps →
-        return (bt_ref[b, live], 0, h, 0)          # revisit elision, no DMA
+        return (bt_ref[b, live], h, 0, 0)          # revisit elision, no DMA
+
+    def split_index(b, h, s, j, bt_ref, cl_ref):
+        return (b, h, s, 0, 0)
 
     grid = (B, Hkv, n_splits, npb)
     kernel = functools.partial(_decode_body, scale=scale, bs=bs,
@@ -151,16 +164,13 @@ def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, rep, Dh), lambda b, h, s, j, bt, cl: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, Dh), kv_index),
-            pl.BlockSpec((1, bs, 1, Dh), kv_index),
+            pl.BlockSpec((1, 1, bs, Dh), kv_index),
+            pl.BlockSpec((1, 1, bs, Dh), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, rep, Dh),
-                         lambda b, h, s, j, bt, cl: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, rep),
-                         lambda b, h, s, j, bt, cl: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, rep),
-                         lambda b, h, s, j, bt, cl: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, rep, Dh), split_index),
+            pl.BlockSpec((1, 1, 1, rep, _STAT_LANES), split_index),
+            pl.BlockSpec((1, 1, 1, rep, _STAT_LANES), split_index),
         ],
         scratch_shapes=[
             pltpu.VMEM((rep, Dh), jnp.float32),          # acc
@@ -173,9 +183,11 @@ def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, n_splits, rep, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_splits, rep), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_splits, rep), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_splits, rep, _STAT_LANES),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_splits, rep, _STAT_LANES),
+                                 jnp.float32),
         ],
         interpret=interpret,
     )(block_table, cache_len, qr, k_pool, v_pool)
-    return combine_splits(acc, m, l, q.dtype)
+    return combine_splits(acc, m[..., 0], l[..., 0], q.dtype)

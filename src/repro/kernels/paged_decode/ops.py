@@ -52,13 +52,13 @@ def _paged_decode_jit(q, k_pool, v_pool, block_table, cache_len, *,
 def paged_decode_attention(q, k_pool, v_pool, block_table, cache_len, *,
                            scale=None, block_kv=None, n_splits=None,
                            impl=None):
-    """q: (B, H, Dh); k/v_pool: (P, bs, Hkv, Dh); block_table: (B, NB)
+    """q: (B, H, Dh); k/v_pool: (P, Hkv, bs, Dh); block_table: (B, NB)
     int32; cache_len: (B,) int32 → (B, H, Dh), attending logical
     positions ``<= cache_len[b]`` of each row's paged KV history."""
     if block_kv is None or n_splits is None:
         B, H, Dh = q.shape
-        shape = tiling.shape_key(B, H, k_pool.shape[2], Dh,
-                                 block_table.shape[1], k_pool.shape[1],
+        shape = tiling.shape_key(B, H, k_pool.shape[1], Dh,
+                                 block_table.shape[1], k_pool.shape[2],
                                  q.dtype)
         tuned = tuned_config("paged_decode", shape, tiling.default(shape))
         block_kv = block_kv if block_kv is not None else tuned.get(

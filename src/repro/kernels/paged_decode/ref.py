@@ -24,7 +24,7 @@ NEG_INF = -1e30
 
 def paged_decode_ref(q, k_pool, v_pool, block_table, cache_len, *,
                      scale: float | None = None):
-    """q: (B, H, Dh); k/v_pool: (P, bs, Hkv, Dh); block_table: (B, NB)
+    """q: (B, H, Dh); k/v_pool: (P, Hkv, bs, Dh); block_table: (B, NB)
     int32; cache_len: (B,) int32 → (B, H, Dh).
 
     ``cache_len[b]`` is row b's highest valid logical position (the
@@ -33,13 +33,16 @@ def paged_decode_ref(q, k_pool, v_pool, block_table, cache_len, *,
     share one KV head.
     """
     B, H, Dh = q.shape
-    bs, Hkv = k_pool.shape[1], k_pool.shape[2]
+    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
     NB = block_table.shape[1]
     rep = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(Dh)
 
-    k = k_pool[block_table].reshape(B, NB * bs, Hkv, Dh).astype(jnp.float32)
-    v = v_pool[block_table].reshape(B, NB * bs, Hkv, Dh).astype(jnp.float32)
+    def logical(pool):  # (B, NB, Hkv, bs, Dh) → (B, NB·bs, Hkv, Dh)
+        return (pool[block_table].transpose(0, 1, 3, 2, 4)
+                .reshape(B, NB * bs, Hkv, Dh).astype(jnp.float32))
+
+    k, v = logical(k_pool), logical(v_pool)
     qr = (q.astype(jnp.float32) * scale).reshape(B, Hkv, rep, Dh)
 
     s = jnp.einsum("bgrd,bkgd->bgrk", qr, k)               # (B, Hkv, rep, L)

@@ -78,17 +78,18 @@ def cost(shape: dict, config: dict) -> KernelCost:
 
     # qk^T + pv over live keys only (early exit) for every query head
     flops = 4.0 * B * H * live * bs * Dh
-    # touched KV (live blocks, once per kv head via revisit elision) +
-    # q in / combined o out + f32 split partials (acc, m, l) written by
+    # touched KV (live (bs, Dh) head tiles of the head-major pool, once
+    # per kv head via revisit elision) + q in / combined o out + f32
+    # split partials (acc, and m/l padded to a 128-lane row) written by
     # the kernel and re-read by the combine + the int32 table/cache_len
     hbm = (bpe * 2.0 * B * Hkv * live * bs * Dh
            + bpe * 2.0 * B * H * Dh
-           + 4.0 * 2.0 * B * H * ns * (Dh + 2)
+           + 4.0 * 2.0 * B * H * ns * (Dh + 2 * 128)
            + 4.0 * (B * NB + B))
-    vmem = (bpe * (rep * Dh + 2 * bs * Dh)      # q block + k/v pool blocks
+    vmem = (bpe * (rep * Dh + 2 * bs * Dh)      # q block + k/v head tiles
             + 4.0 * rep * Dh * 2                # f32 acc scratch + o partial
             + 4.0 * rep * bkv                   # f32 score/prob chunk
-            + 4.0 * 2 * rep * 128)              # m/l lane-padded stats
+            + 4.0 * 2 * rep * 128 * 2)          # m/l lane-padded scratch + out
     # Sequenced chain per (b, h): live grid steps (dead ones are clamped
     # revisits — free) × loop trips; splits run on parallel cores.
     npb = -(-NB // ns)
@@ -130,8 +131,8 @@ def _runner(shape: dict, config: dict):
     NB, bs = shape["NB"], shape["bs"]
     P = B * NB + 1
     q = jnp.asarray(rng.standard_normal((B, shape["H"], Dh)), shape["dtype"])
-    kp = jnp.asarray(rng.standard_normal((P, bs, Hkv, Dh)), shape["dtype"])
-    vp = jnp.asarray(rng.standard_normal((P, bs, Hkv, Dh)), shape["dtype"])
+    kp = jnp.asarray(rng.standard_normal((P, Hkv, bs, Dh)), shape["dtype"])
+    vp = jnp.asarray(rng.standard_normal((P, Hkv, bs, Dh)), shape["dtype"])
     bt = jnp.asarray(1 + np.arange(B * NB).reshape(B, NB), jnp.int32)
     cl = jnp.asarray(np.full(B, NB * bs // 2, np.int32))  # steady state
     bkv, ns = config["block_kv"], config["n_splits"]

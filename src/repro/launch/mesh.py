@@ -11,7 +11,7 @@ from __future__ import annotations
 import jax
 
 __all__ = ["MeshSpecError", "validate_mesh_spec", "make_production_mesh",
-           "make_mesh", "dp_axes", "TPU_V5E"]
+           "make_mesh", "dp_axes"]
 
 
 class MeshSpecError(ValueError):
@@ -96,11 +96,3 @@ def dp_axes(mesh) -> tuple[str, ...]:
     names = mesh.axis_names
     return tuple(a for a in ("pod", "data") if a in names)
 
-
-# TPU v5e hardware constants (per chip) — roofline denominators.
-TPU_V5E = {
-    "peak_flops_bf16": 197e12,   # FLOP/s
-    "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link (~45-50 GB/s each direction)
-    "hbm_bytes": 16e9,           # capacity
-}

@@ -18,6 +18,7 @@ import json
 
 from repro.configs.base import ShapeSpec
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.core.cache_dirs import use_compile_cache
 from repro.optim.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -70,6 +71,7 @@ def main() -> None:
                     help="microbatches per step assumed by the planner's "
                          "pipeline-bubble model")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     shape = ShapeSpec("cli", args.seq, args.batch, "train")

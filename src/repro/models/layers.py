@@ -279,24 +279,24 @@ def _paged_decode_fast_path(q, k_pool, v_pool, block_table, cache_len):
       ``kernel``    always the Pallas kernel (interpret mode off-TPU)
       ``interpret`` force interpret mode (debugging/tests)
       ``gather``    always the gather + dense fallback
+
+    Any other value raises.
     """
     import os
 
     mode = os.environ.get("REPRO_PAGED_DECODE", "auto").lower()
-    if mode == "gather":
-        return None
-    import jax as _jax
-
-    if mode == "auto" and _jax.default_backend() != "tpu":
+    impls = {"auto": "kernel", "kernel": "kernel", "interpret": "interpret",
+             "gather": None}
+    if mode not in impls:
+        raise ValueError(f"REPRO_PAGED_DECODE={mode!r}; expected one of "
+                         f"{sorted(impls)}")
+    if mode == "gather" or (mode == "auto"
+                            and jax.default_backend() != "tpu"):
         return None
     from repro.kernels.paged_decode import paged_decode_attention
 
-    impl = {"auto": "kernel", "kernel": "kernel",
-            "interpret": "interpret"}.get(mode)
-    if impl is None:  # unknown value: be conservative, gather
-        return None
     o = paged_decode_attention(q[:, 0], k_pool, v_pool, block_table,
-                               cache_len, impl=impl)
+                               cache_len, impl=impls[mode])
     return o[:, None]  # (B, 1, H, Dh)
 
 
@@ -305,7 +305,7 @@ def attention_block(
     positions,
     mask_kind: str,
     cache=None,          # (k_cache, v_cache): (B, Smax, Hkv, Dh) or None,
-    #                      or a paged pool {"k_pool","v_pool"}: (P, bs, Hkv, Dh)
+    #                      or a paged pool {"k_pool","v_pool"}: (P, Hkv, bs, Dh)
     cache_len=None,      # int32 scalar OR per-row (B,) vector: cache fill
     kv_source=None,      # cross-attention memory (B, Sm, D)
     pos_offset=None,     # (B,) left-padding per row (ragged prompts)
@@ -352,21 +352,22 @@ def attention_block(
         elif "k_pool" in cache:
             # Paged path: scatter the S new tokens' KV into their blocks.
             # Slot i's token t lands at logical position cache_len[i] + t =
-            # physical (block_table[i, pos//bs], pos % bs).  S == 1 is the
+            # physical (block_table[i, pos//bs], :, pos % bs) of the
+            # head-major pool (P, Hkv, bs, Dh).  S == 1 is the
             # decode step; S > 1 is a chunked-prefill chunk riding the same
             # path (right-padded rows route their junk positions to block
             # indices past the row's live table entries — the caller sizes
             # the table so those columns exist and point at scratch).
             kp, vp = cache["k_pool"], cache["v_pool"]
-            bs_blk = kp.shape[1]
+            bs_blk = kp.shape[2]
             cl = (cache_len if jnp.ndim(cache_len)
                   else jnp.full((B,), cache_len, jnp.int32))
             tok_pos = cl[:, None] + jnp.arange(S)            # (B, S)
             blk = tok_pos // bs_blk
             off = tok_pos % bs_blk
             phys = block_table[jnp.arange(B)[:, None], blk]  # (B, S)
-            kp = kp.at[phys, off].set(k.astype(kp.dtype))
-            vp = vp.at[phys, off].set(v.astype(vp.dtype))
+            kp = kp.at[phys, :, off].set(k.astype(kp.dtype))  # (B,S,Hkv,Dh)
+            vp = vp.at[phys, :, off].set(v.astype(vp.dtype))
             new_cache = {"k_pool": kp, "v_pool": vp}
             kv_len = cl + S - 1                              # (B,)
             if S == 1 and mask_kind == "causal":
@@ -380,8 +381,11 @@ def attention_block(
                     out = jnp.einsum("bshk,hkd->bsd", o,
                                      p["wo"].reshape(H, Dh, D))
                     return out, new_cache
-            k_full = kp[block_table].reshape(B, -1, Hkv, Dh)  # (B, NB·bs, ·)
-            v_full = vp[block_table].reshape(B, -1, Hkv, Dh)
+            # (B, NB, Hkv, bs, Dh) → logical view (B, NB·bs, Hkv, Dh)
+            k_full = kp[block_table].transpose(0, 1, 3, 2, 4).reshape(
+                B, -1, Hkv, Dh)
+            v_full = vp[block_table].transpose(0, 1, 3, 2, 4).reshape(
+                B, -1, Hkv, Dh)
             k_pos = jnp.arange(k_full.shape[1])
         else:
             kc, vc = cache["k"], cache["v"]

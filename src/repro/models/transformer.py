@@ -518,6 +518,10 @@ def paged_cache_shapes(cfg: ArchConfig, n_blocks: int, block_size: int) -> dict:
     ``block_table``).  Pool capacity is a *budget*, not ``n_slots ×
     max_len`` — long-context configs no longer allocate dense caches they
     never fill.  Physical block 0 is reserved as scratch for idle slots.
+
+    Blocks are head-major, ``(n_scan, n_blocks, Hkv, block_size, Dh)``:
+    one KV head of one block is a contiguous ``(block_size, Dh)`` tile,
+    the unit the ``paged_decode`` kernel DMAs per grid step.
     """
     n_scan, plan = layer_plan(cfg)
     out = {}
@@ -526,7 +530,7 @@ def paged_cache_shapes(cfg: ArchConfig, n_blocks: int, block_size: int) -> dict:
             raise ValueError(
                 f"paged KV cache needs a pure self-attention stack; "
                 f"{cfg.name} has a {mixer!r} mixer (use the dense cache)")
-        s = (n_scan, n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim_)
+        s = (n_scan, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim_)
         out[f"sub{i}"] = {"k_pool": s, "v_pool": s}
     return out
 

@@ -163,7 +163,9 @@ class PagedKVCache:
                 # dense_leaf: (n_scan, 1, L, Hkv, Dh) — drop the B=1 axis,
                 # roll the left-padding off so real token i lands at slot i
                 d = jnp.roll(dense_leaf[:, 0], -pad, axis=1)
-                blocks = d.reshape(d.shape[0], nb, bs, *d.shape[2:])
+                # (n_scan, nb, bs, Hkv, Dh) → head-major pool blocks
+                blocks = d.reshape(d.shape[0], nb, bs, *d.shape[2:]).transpose(
+                    0, 1, 3, 2, 4)
                 return pool_leaf.at[:, phys].set(blocks.astype(pool_leaf.dtype))
 
             return {

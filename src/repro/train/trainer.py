@@ -12,7 +12,11 @@ Production behaviours, all exercised by tests:
     slow (pod/DCI) axis,
   * perf4sight admission gate: refuse to even build the jitted step when the
     predicted per-device HBM exceeds the budget (the paper's §6.4 safety
-    argument, applied to the launcher).
+    argument, applied to the launcher),
+  * data/tensor parallelism: given a ``mesh``, the step is jitted with the
+    ``distributed.sharding`` state and batch shardings, state and batches
+    are placed on the mesh, and the model's activation hints see the mesh
+    while the step traces.
 """
 
 from __future__ import annotations
@@ -26,6 +30,8 @@ import numpy as np
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.data.pipeline import TokenPipeline, make_batch
+from repro.distributed import sharding as sh
+from repro.models import layers as L
 from repro.models import transformer as T
 from repro.optim.compression import compress_grads, init_error_state
 from repro.optim.optimizer import OptimizerConfig, apply_updates, init_opt_state
@@ -74,7 +80,6 @@ class Trainer:
         tcfg: TrainerConfig | None = None,
         *,
         mesh=None,
-        state_shardings=None,
         admission=None,   # callable(cfg, shape) -> (ok, info)
     ):
         self.cfg = cfg
@@ -92,7 +97,21 @@ class Trainer:
                 raise RuntimeError(f"admission denied: {info}")
 
         self._compression = self.tcfg.grad_compression
-        self._step_fn = jax.jit(self._make_step(), donate_argnums=(0,))
+        # NamedSharding trees on ``mesh`` (None without one)
+        self.state_shardings = self.batch_shardings = None
+        if mesh is None:
+            self._step_fn = jax.jit(self._make_step(), donate_argnums=(0,))
+        else:
+            specs = sh.state_pspecs(cfg, mesh, kind=self.opt_cfg.kind)
+            if self._compression is not None:
+                specs["err"] = specs["params"]
+            self.state_shardings = sh.to_named(mesh, specs)
+            self.batch_shardings = sh.to_named(mesh, sh.batch_pspecs(cfg, shape, mesh))
+            self._step_fn = jax.jit(
+                self._make_step(),
+                in_shardings=(self.state_shardings, self.batch_shardings),
+                out_shardings=(self.state_shardings, None),
+                donate_argnums=(0,))
 
     # ------------------------------------------------------------------
 
@@ -113,22 +132,46 @@ class Trainer:
 
         return step_fn
 
+    def _slots(self, params) -> dict:
+        """Optimizer (and error-feedback) state for ``params``."""
+        slots = {"opt": init_opt_state(params, self.opt_cfg)}
+        if self._compression is not None:
+            slots["err"] = init_error_state(params)
+        return slots
+
     def init_state(self) -> dict:
         params = T.init_params(self.cfg, self.tcfg.seed)
-        params = jax.tree.map(jnp.asarray, params)
-        state = {"params": params,
-                 "opt": init_opt_state(params, self.opt_cfg)}
-        if self._compression is not None:
-            state["err"] = init_error_state(params)
-        return state
+        if self.state_shardings is None:
+            params = jax.tree.map(jnp.asarray, params)
+            return {"params": params, **self._slots(params)}
+        # Place each shard straight from the host and build the slots
+        # sharded, so no device ever holds the whole state.
+        shard = dict(self.state_shardings)
+        params = jax.device_put(params, shard.pop("params"))
+        return {"params": params,
+                **jax.jit(self._slots, out_shardings=shard)(params)}
 
     def restore_or_init(self) -> tuple[int, dict]:
         d = self.tcfg.ckpt_dir
         if d and ckpt.latest_step(d) is not None:
             template = self.init_state()
-            step, state = ckpt.restore_checkpoint(d, template=template)
+            step, state = ckpt.restore_checkpoint(d, template=template,
+                                                  shardings=self.state_shardings)
             return step + 1, state
         return 0, self.init_state()
+
+    def _run_step(self, state, batch):
+        if self.mesh is None:
+            return self._step_fn(state, batch)
+        batch = jax.device_put(batch, self.batch_shardings)
+        # The hint mesh is read while the step traces (first call only);
+        # restore the caller's afterwards so other models stay unsharded.
+        prev = (L._HINT_MESH, L.SP_HINT)
+        L.set_hint_mesh(self.mesh)
+        try:
+            return self._step_fn(state, batch)
+        finally:
+            L.set_hint_mesh(prev[0], sp=prev[1])
 
     # ------------------------------------------------------------------
 
@@ -139,7 +182,7 @@ class Trainer:
                 raise RuntimeError(f"injected failure at step {step}")
             batch = make_batch(self.cfg, self.shape, step, self.tcfg.seed)
             t0 = time.perf_counter()
-            state, metrics = self._step_fn(state, batch)
+            state, metrics = self._run_step(state, batch)
             jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
             slow = self.monitor.observe(step, dt)

@@ -1,6 +1,6 @@
 """Suite-wide isolation: the kernel autotuner's implicit lookups (model
-tracing, ops wrappers) must never write to the user-level tuning cache
-(~/.cache/repro) from tests.  Redirect the default cache file to a
+tracing, ops wrappers) must never write to the checkout's tuning cache
+(``.cache/kernel_tuning.json``) from tests.  Redirect the default cache file to a
 per-session scratch path before any tuner is created."""
 
 import os

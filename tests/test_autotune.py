@@ -356,3 +356,33 @@ def test_warm_autotune_populates_cache(tmp_path):
     stats2 = warm_autotune(cfg, batch_size=2, seq_len=32,
                            stages=("prefill", "decode"))
     assert stats2["misses"] == 0 and stats2["hits"] >= 1
+
+
+def test_tuned_config_propagates_kernel_errors(tmp_path, monkeypatch):
+    """A candidate that fails while it is timed (as a kernel the chip's
+    compiler refuses would) fails the lookup instead of silently becoming
+    the default config."""
+    from repro.kernels import autotune as at
+
+    def refused(shape, config):
+        raise RuntimeError("Mosaic refused the block shape")
+
+    monkeypatch.setitem(at._TILINGS, "refused_probe", at.TilingModel(
+        name="refused_probe", candidates=lambda s: [{"b": 1}, {"b": 2}],
+        cost=lambda s, c: KernelCost(op="probe", flops=1.0, hbm_bytes=1.0),
+        default=lambda s: {"b": 1}, runner=refused))
+    set_tuner(KernelTuner(device=get_device(TPU),
+                          cache=str(tmp_path / "t.json"), measure=True))
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        at.tuned_config("refused_probe", {"n": 1}, {"b": 1})
+
+
+def test_tuned_config_unwritable_cache_returns_default(tmp_path):
+    from repro.kernels.autotune import tuned_config
+
+    blocker = tmp_path / "not_a_dir"
+    blocker.write_text("")
+    set_tuner(KernelTuner(device=get_device(TPU),
+                          cache=str(blocker / "t.json"), measure=False))
+    assert tuned_config("paged_decode", PD_SHAPE, {"sentinel": 1}) == {
+        "sentinel": 1}

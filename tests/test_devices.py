@@ -82,6 +82,36 @@ def test_from_jax_device_registers_uncalibrated_spec():
     assert spec.peak_flops > 0 and spec.hbm_bytes > 0
 
 
+class _FakeJaxDevice:
+    def __init__(self, platform, device_kind, stats=None):
+        self.platform, self.device_kind, self._stats = (
+            platform, device_kind, stats)
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,kind,spec", [
+    ("tpu", "TPU v5 lite", "tpu_v5e"),
+    ("tpu", "TPU v5e", "tpu_v5e"),
+    ("cpu", "cpu", "host_cpu"),
+])
+def test_from_jax_device_looks_up_device_kind(platform, kind, spec):
+    got = from_jax_device(_FakeJaxDevice(platform, kind,
+                                         {"bytes_limit": 15e9}))
+    want = get_device(spec)
+    assert (got.peak_flops, got.hbm_bw) == (want.peak_flops, want.hbm_bw)
+    assert got.hbm_bytes == 15e9
+    assert got.meta == {"platform": platform, "device_kind": kind}
+
+
+@pytest.mark.parametrize("platform,kind", [
+    ("tpu", "TPU v4"), ("gpu", "NVIDIA A100-SXM4-40GB"), ("tpu", "")])
+def test_from_jax_device_unknown_kind_raises(platform, kind):
+    with pytest.raises(KeyError, match="no device spec"):
+        from_jax_device(_FakeJaxDevice(platform, kind))
+
+
 def test_validation():
     with pytest.raises(ValueError):
         DeviceSpec(name="bad", peak_flops=0.0, hbm_bw=1e9)

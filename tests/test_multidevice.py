@@ -57,6 +57,46 @@ def test_sharded_train_step_matches_single_device():
     """)
 
 
+def test_trainer_data_mesh_matches_unsharded():
+    """Trainer(mesh=4x1) jits its step with the sharding rules: the batch
+    is split over 4 devices, and its losses match the unsharded Trainer's
+    from the same seed."""
+    _run("""
+        import jax
+        from repro.configs.registry import get_config
+        from repro.configs.base import ShapeSpec
+        from repro.data.pipeline import make_batch
+        from repro.launch.mesh import make_mesh
+        from repro.models import layers as L
+        from repro.optim.optimizer import OptimizerConfig
+        from repro.train.trainer import Trainer
+
+        cfg = get_config("internlm2-1.8b", reduced=True)
+        shape = ShapeSpec("t", 32, 8, "train")
+        opt = OptimizerConfig(kind="adamw", lr=1e-3, warmup_steps=1,
+                              total_steps=10)
+
+        def losses(mesh):
+            tr = Trainer(cfg, shape, opt, mesh=mesh)
+            out = tr.train(3)
+            return tr, out, [r["loss"] for r in out["history"]]
+
+        _, _, ref = losses(None)
+        tr, out, sharded = losses(make_mesh((4, 1), ("data", "model")))
+        assert L._HINT_MESH is None    # hint mesh restored after the step
+
+        b = jax.device_put(make_batch(cfg, shape, 0), tr.batch_shardings)
+        assert len(b["tokens"].sharding.device_set) == 4
+        assert {s.data.shape for s in b["tokens"].addressable_shards} == {(2, 32)}
+        m = out["state"]["opt"]["m"]["embed"]
+        assert len(m.sharding.device_set) == 4
+
+        for a, c in zip(ref, sharded):
+            assert abs(a - c) / abs(a) < 2e-2, (ref, sharded)
+        print("OK", ref, sharded)
+    """, n_devices=4)
+
+
 def test_moe_arch_sharded_matches():
     _run("""
         import numpy as np, jax, dataclasses

@@ -20,8 +20,8 @@ def _case(B, H, Hkv, Dh, NB, bs, dtype, cache_lens, seed=0):
     rng = np.random.default_rng(seed)
     P = B * NB + 1                       # block 0 = scratch, like the pool
     q = jnp.asarray(rng.standard_normal((B, H, Dh)), dtype)
-    kp = jnp.asarray(rng.standard_normal((P, bs, Hkv, Dh)), dtype)
-    vp = jnp.asarray(rng.standard_normal((P, bs, Hkv, Dh)), dtype)
+    kp = jnp.asarray(rng.standard_normal((P, Hkv, bs, Dh)), dtype)
+    vp = jnp.asarray(rng.standard_normal((P, Hkv, bs, Dh)), dtype)
     bt = jnp.asarray(rng.permutation(B * NB).reshape(B, NB) + 1, jnp.int32)
     cl = jnp.asarray(cache_lens, jnp.int32)
     return q, kp, vp, bt, cl
@@ -31,9 +31,9 @@ def _gather_oracle(q, kp, vp, bt, cl):
     """The layers.py fallback, verbatim semantics: gather the logical
     view, dense causal attention with q at position cache_len."""
     B, H, Dh = q.shape
-    Hkv = kp.shape[2]
-    k = kp[bt].reshape(B, -1, Hkv, Dh)
-    v = vp[bt].reshape(B, -1, Hkv, Dh)
+    Hkv = kp.shape[1]
+    k = kp[bt].transpose(0, 1, 3, 2, 4).reshape(B, -1, Hkv, Dh)
+    v = vp[bt].transpose(0, 1, 3, 2, 4).reshape(B, -1, Hkv, Dh)
     o = blocked_attention(
         q[:, None], k, v,
         q_positions=cl[:, None], k_positions=jnp.arange(k.shape[1]),
@@ -90,9 +90,9 @@ def test_scatter_mask_boundary_off_by_one():
     cl_val = bs                                 # block 1, offset 0
     phys = int(bt[0, cl_val // bs])
     q = jnp.ones_like(q)                        # q·k_marker >> any other
-    kp = kp.at[phys, cl_val % bs].set(
+    kp = kp.at[phys, :, cl_val % bs].set(
         100.0 * math.sqrt(Dh) * jnp.ones((Hkv, Dh)))
-    marker_v = vp[phys, cl_val % bs]            # (Hkv, Dh)
+    marker_v = vp[phys, :, cl_val % bs]         # (Hkv, Dh)
     want = jnp.broadcast_to(marker_v[:, None],
                             (Hkv, H // Hkv, Dh)).reshape(1, H, Dh)
 
@@ -118,8 +118,8 @@ def test_empty_row_cache_len_zero():
     q, kp, vp, bt, cl = _case(2, 4, 2, 32, 2, 16, "float32", [0, 0])
     ref = paged_decode_ref(q, kp, vp, bt, cl)
     want = jnp.broadcast_to(
-        kp[bt[:, 0], 0][:, :, None].astype(jnp.float32) * 0
-        + vp[bt[:, 0], 0][:, :, None], (2, 2, 2, 32)).reshape(2, 4, 32)
+        kp[bt[:, 0], :, 0][:, :, None].astype(jnp.float32) * 0
+        + vp[bt[:, 0], :, 0][:, :, None], (2, 2, 2, 32)).reshape(2, 4, 32)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     ker = paged_decode_kernel(q, kp, vp, bt, cl, interpret=True)
@@ -181,3 +181,12 @@ def test_layers_paged_branch_kernel_vs_gather(monkeypatch):
                 np.asarray(pool_g[sub][name], np.float32),
                 np.asarray(pool_k[sub][name], np.float32),
                 rtol=8e-2, atol=8e-2)
+
+
+def test_unknown_paged_decode_mode_raises(monkeypatch):
+    from repro.models.layers import _paged_decode_fast_path
+
+    q, kp, vp, bt, cl = _case(1, 4, 2, 32, 2, 16, "float32", [3])
+    monkeypatch.setenv("REPRO_PAGED_DECODE", "kernal")
+    with pytest.raises(ValueError, match="REPRO_PAGED_DECODE"):
+        _paged_decode_fast_path(q[:, None], kp, vp, bt, cl)
