@@ -189,5 +189,6 @@ def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
                                  jnp.float32),
         ],
         interpret=interpret,
+        name="paged_decode",
     )(block_table, cache_len, qr, k_pool, v_pool)
     return combine_splits(acc, m[..., 0], l[..., 0], q.dtype)

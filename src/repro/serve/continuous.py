@@ -62,6 +62,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models import transformer as T
@@ -180,14 +181,14 @@ class ContinuousEngine:
         self._key = jax.random.PRNGKey(scfg.seed)
         temp = float(scfg.temperature)
 
-        def sample(logits, key):
+        def serve_sample(logits, key):
             z = logits[:, -1].astype(jnp.float32)
             if temp <= 0:
                 return jnp.argmax(z, axis=-1).astype(jnp.int32)
             return jax.random.categorical(key, z / temp, axis=-1).astype(
                 jnp.int32)
 
-        self._sample = jax.jit(sample)
+        self._sample = jax.jit(serve_sample)
         self._prefills: dict[int, object] = {}
         self._decodes: dict[int, object] = {}
         self._chunks: dict[tuple[int, int], object] = {}
@@ -236,22 +237,29 @@ class ContinuousEngine:
         return request
 
     # ------------------------------------------------------------------
-    # jit memos
+    # jit memos.  Each program is a named function, so that a profiler
+    # trace names its runs ``jit_serve_prefill``, ``jit_serve_chunk``,
+    # ``jit_serve_decode`` and ``jit_serve_sample``.
 
     def _prefill_fn(self, width: int):
         fn = self._prefills.get(width)
         if fn is None:
             cache_len_dim = -(-width // self.kv.block_size) * self.kv.block_size
-            fn = jax.jit(lambda p, b: T.prefill(p, b, self.cfg,
-                                                max_len=cache_len_dim))
+
+            def serve_prefill(p, b):
+                return T.prefill(p, b, self.cfg, max_len=cache_len_dim)
+
+            fn = jax.jit(serve_prefill)
             self._prefills[width] = fn
         return fn
 
     def _decode_fn(self, nb: int):
         fn = self._decodes.get(nb)
         if fn is None:
-            fn = jax.jit(lambda p, c, b: T.decode_step(p, c, b, self.cfg),
-                         donate_argnums=(1,))
+            def serve_decode(p, c, b):
+                return T.decode_step(p, c, b, self.cfg)
+
+            fn = jax.jit(serve_decode, donate_argnums=(1,))
             self._decodes[nb] = fn
         return fn
 
@@ -261,8 +269,10 @@ class ContinuousEngine:
         # decode_step path (scatter S tokens, attend causally).
         fn = self._chunks.get((width, nb))
         if fn is None:
-            fn = jax.jit(lambda p, c, b: T.decode_step(p, c, b, self.cfg),
-                         donate_argnums=(1,))
+            def serve_chunk(p, c, b):
+                return T.decode_step(p, c, b, self.cfg)
+
+            fn = jax.jit(serve_chunk, donate_argnums=(1,))
             self._chunks[(width, nb)] = fn
         return fn
 
@@ -391,6 +401,8 @@ class ContinuousEngine:
             if req.admit_seq is None:      # age = FIRST admission order
                 req.admit_seq = self._admit_seq
                 self._admit_seq += 1
+                req.t_admitted = self._now()
+                req.step_admitted = self._step
             self._prefill_into(req, self.slots.index(None))
 
     def _prefill_into(self, req: Request, slot: int) -> None:
@@ -418,18 +430,20 @@ class ContinuousEngine:
         width = min(_next_pow2(max(S, self.kv.block_size)),
                     -(-self.scfg.max_len // self.kv.block_size)
                     * self.kv.block_size)
-        if self._has_decodable():
-            self.max_prefill_stall_tokens = max(
-                self.max_prefill_stall_tokens, width)
-        pad = width - S
-        tokens = np.zeros((1, width), np.int32)
-        tokens[0, pad:] = seq
-        out = self._prefill_fn(width)(self.params, {
-            "tokens": jnp.asarray(tokens),
-            "pos_offset": jnp.asarray([pad], jnp.int32),
-        })
+        with TraceAnnotation("serve.prefill", rid=req.rid, width=width):
+            if self._has_decodable():
+                self.max_prefill_stall_tokens = max(
+                    self.max_prefill_stall_tokens, width)
+            pad = width - S
+            tokens = np.zeros((1, width), np.int32)
+            tokens[0, pad:] = seq
+            out = self._prefill_fn(width)(self.params, {
+                "tokens": jnp.asarray(tokens),
+                "pos_offset": jnp.asarray([pad], jnp.int32),
+            })
         self._key, sub = jax.random.split(self._key)
-        tok = int(np.asarray(self._sample(out["logits"], sub))[0])
+        with TraceAnnotation("serve.sync"):
+            tok = int(np.asarray(self._sample(out["logits"], sub))[0])
         req.state = RequestState.RUNNING
         req.slot = slot
         req.tokens.append(tok)
@@ -463,21 +477,23 @@ class ContinuousEngine:
             s0 = req.prefill_pos
             clen = min(chunk, len(seq) - s0)
             width = _next_pow2(clen)
-            if self._has_decodable():
-                self.max_prefill_stall_tokens = max(
-                    self.max_prefill_stall_tokens, width)
-            tokens = np.zeros((1, width), np.int32)
-            tokens[0, :clen] = seq[s0:s0 + clen]
             nb = _next_pow2((s0 + width - 1) // bs + 1)
-            table = np.zeros((1, nb), np.int32)   # pad → scratch block 0
-            table[0, :len(req.blocks[:nb])] = req.blocks[:nb]
-            table = jnp.asarray(table)
-            logits, self.kv.pool = self._chunk_fn(width, nb)(
-                self.params, self.kv.pool, {
-                    "tokens": jnp.asarray(tokens),
-                    "cache_len": jnp.asarray([s0], jnp.int32),
-                    "block_table": table,
-                })
+            with TraceAnnotation("serve.chunk", rid=req.rid, width=width,
+                                 nb=nb):
+                if self._has_decodable():
+                    self.max_prefill_stall_tokens = max(
+                        self.max_prefill_stall_tokens, width)
+                tokens = np.zeros((1, width), np.int32)
+                tokens[0, :clen] = seq[s0:s0 + clen]
+                table = np.zeros((1, nb), np.int32)   # pad → scratch block 0
+                table[0, :len(req.blocks[:nb])] = req.blocks[:nb]
+                table = jnp.asarray(table)
+                logits, self.kv.pool = self._chunk_fn(width, nb)(
+                    self.params, self.kv.pool, {
+                        "tokens": jnp.asarray(tokens),
+                        "cache_len": jnp.asarray([s0], jnp.int32),
+                        "block_table": table,
+                    })
             self.counters["prefill_chunks"] += 1
             req.prefill_pos = s0 + clen
             self._cache_len[slot] = req.prefill_pos
@@ -486,8 +502,9 @@ class ContinuousEngine:
             # Final chunk: sample the first new token; the slot joins the
             # decodable set from the next _decode_once on.
             self._key, sub = jax.random.split(self._key)
-            tok = int(np.asarray(self._sample(logits[:, clen - 1:clen],
-                                              sub))[0])
+            with TraceAnnotation("serve.sync"):
+                tok = int(np.asarray(self._sample(logits[:, clen - 1:clen],
+                                                  sub))[0])
             self._prefilling[slot] = False
             req.prefill_pos = 0
             req.tokens.append(tok)
@@ -556,7 +573,8 @@ class ContinuousEngine:
     # decode (all occupied slots advance one token)
 
     def _decode_once(self) -> None:
-        self._grow_blocks()
+        with TraceAnnotation("serve.grow"):
+            self._grow_blocks()
         # Mid-prefill slots are occupied but not decodable: their table
         # rows stay empty (scratch) and cache_len is masked to 0, so the
         # batched step writes their junk token to scratch block 0.
@@ -567,34 +585,38 @@ class ContinuousEngine:
         nb_need = max(int(self._cache_len[i]) // self.kv.block_size + 1
                       for i in active)
         nb = min(_next_pow2(nb_need), self.kv.blocks_per_seq)
-        decodable = np.array([r is not None and not self._prefilling[i]
-                              for i, r in enumerate(self.slots)])
-        table = self.kv.table_array(
-            [r.blocks[:nb] if decodable[i] else []
-             for i, r in enumerate(self.slots)], nb)
-        batch = {
-            "tokens": jnp.asarray(self._last_tok[:, None]),
-            "cache_len": jnp.asarray(
-                np.where(decodable, self._cache_len, 0).astype(np.int32)),
-            "block_table": table,
-        }
-        per_block = self.kv.bytes / self.kv.n_blocks
-        self.kv_gathered_bytes += len(self.slots) * nb * per_block
-        self.kv_touched_bytes += per_block * sum(
-            int(self._cache_len[i]) // self.kv.block_size + 1 for i in active)
-        logits, self.kv.pool = self._decode_fn(nb)(
-            self.params, self.kv.pool, batch)
+        with TraceAnnotation("serve.decode", rows=len(active), nb=nb):
+            decodable = np.array([r is not None and not self._prefilling[i]
+                                  for i, r in enumerate(self.slots)])
+            table = self.kv.table_array(
+                [r.blocks[:nb] if decodable[i] else []
+                 for i, r in enumerate(self.slots)], nb)
+            batch = {
+                "tokens": jnp.asarray(self._last_tok[:, None]),
+                "cache_len": jnp.asarray(
+                    np.where(decodable, self._cache_len, 0).astype(np.int32)),
+                "block_table": table,
+            }
+            per_block = self.kv.bytes / self.kv.n_blocks
+            self.kv_gathered_bytes += len(self.slots) * nb * per_block
+            self.kv_touched_bytes += per_block * sum(
+                int(self._cache_len[i]) // self.kv.block_size + 1
+                for i in active)
+            logits, self.kv.pool = self._decode_fn(nb)(
+                self.params, self.kv.pool, batch)
         self._key, sub = jax.random.split(self._key)
-        toks = np.asarray(self._sample(logits, sub))
+        with TraceAnnotation("serve.sync"):
+            toks = np.asarray(self._sample(logits, sub))
         self.decode_steps += 1
-        now = self._now()
-        for i in active:
-            req = self.slots[i]
-            tok = int(toks[i])
-            req.tokens.append(tok)
-            self._cache_len[i] += 1
-            self._last_tok[i] = tok
-            self._retire_if_done(req, now)
+        with TraceAnnotation("serve.commit"):
+            now = self._now()
+            for i in active:
+                req = self.slots[i]
+                tok = int(toks[i])
+                req.tokens.append(tok)
+                self._cache_len[i] += 1
+                self._last_tok[i] = tok
+                self._retire_if_done(req, now)
 
     def _retire_if_done(self, req: Request, now: float | None = None) -> None:
         done = (req.tokens[-1] == self.scfg.eos_id
@@ -618,33 +640,41 @@ class ContinuousEngine:
         """One engine iteration: expire stale work, admit+prefill into
         free slots, then one ragged decode step for every occupied slot.
 
+        Each phase is a host span in the profiler's trace (``serve.*``,
+        docs/serve.md "Observability"); with the profiler off a span
+        costs under a microsecond.
+
         Every failure the fault plan can inject here — pool-allocation
         denial, backend exceptions, slow steps — is handled inside the
         call: nothing escapes ``step`` short of a real model bug."""
         self._step += 1
-        if self.faults is not None:
-            self.faults.begin_step(self._step)
-            self._skew_s += float(self.faults.fire("slow"))
-        self._expire_sweep()
-        self._admissions()
-        self._prefill_chunks()
-        decodable_before = self._has_decodable()
-        before = self.decode_steps
-        self._decode_once()
-        if (decodable_before and self.decode_steps == before
-                and self._has_decodable()):
-            # A decodable slot existed, survived the step, and still no
-            # decode ran — a genuine stall (0 by construction: chunked
-            # prefill interleaves with decode instead of displacing it).
-            self._stall_run += 1
-            self.max_decode_stall_steps = max(self.max_decode_stall_steps,
-                                              self._stall_run)
-        else:
-            self._stall_run = 0
-        if self.failover is not None:
-            self.counters["failovers"] = self.failover.health.failovers
-            if self.failover.degraded:
-                self.counters["degraded_steps"] += 1
+        with TraceAnnotation("serve.step", step=self._step):
+            if self.faults is not None:
+                self.faults.begin_step(self._step)
+                self._skew_s += float(self.faults.fire("slow"))
+            with TraceAnnotation("serve.expire"):
+                self._expire_sweep()
+            with TraceAnnotation("serve.admit"):
+                self._admissions()
+            self._prefill_chunks()
+            decodable_before = self._has_decodable()
+            before = self.decode_steps
+            self._decode_once()
+            if (decodable_before and self.decode_steps == before
+                    and self._has_decodable()):
+                # A decodable slot existed, survived the step, and still
+                # no decode ran — a genuine stall (0 by construction:
+                # chunked prefill interleaves with decode instead of
+                # displacing it).
+                self._stall_run += 1
+                self.max_decode_stall_steps = max(
+                    self.max_decode_stall_steps, self._stall_run)
+            else:
+                self._stall_run = 0
+            if self.failover is not None:
+                self.counters["failovers"] = self.failover.health.failovers
+                if self.failover.degraded:
+                    self.counters["degraded_steps"] += 1
 
     def run(self, requests: list[Request] | None = None, *,
             max_steps: int = 100_000) -> list[Request]:

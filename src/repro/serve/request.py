@@ -69,13 +69,18 @@ class Request:
     defer_retries: int = 0              # DEFER backoff attempts so far
     retry_at_step: int = 0              # engine step before which not re-priced
 
-    # wall-clock marks (seconds, time.perf_counter domain)
+    # wall-clock marks (seconds, time.perf_counter domain).  t_admitted
+    # is the FIRST admission (a resumed request keeps it; a refused one
+    # has none), so TTFT = queueing (t_admitted - t_arrival) + prefill
+    # (t_first_token - t_admitted).
     t_arrival: float = field(default_factory=time.perf_counter)
+    t_admitted: float | None = None
     t_first_token: float | None = None
     t_finished: float | None = None
     # engine-step marks — the deterministic (noise-free) TTFT the serve
     # bench gates on: step_first_token - step_submitted
     step_submitted: int | None = None
+    step_admitted: int | None = None
     step_first_token: int | None = None
 
     def __post_init__(self):
