@@ -7,13 +7,16 @@ The block table and per-row cache lengths ride in scalar-prefetch SMEM
 one KV head straight from HBM; the gathered ``(B, NB·bs)`` logical view
 the XLA fallback materialises never exists.
 
-The pool is head-major, ``(P, Hkv, bs, Dh)``, so one head's block is a
-``(bs, Dh)`` tile whose last two dims are whole array dims.  Mosaic
-refuses the token-major ``(P, bs, Hkv, Dh)`` layout: a one-head block
-there has a second-minor block dim of 1 against Hkv, and indexing the
-head inside an all-heads block is an unaligned sublane slice.  The
-running (m, l) stats are written out padded to a full 128-lane row for
-the same reason.
+The pool is head-major, ``(L, P, Hkv, bs, Dh)``: the serve path's pool
+stacks every layer's blocks, and the layer index rides in SMEM as a
+third scalar-prefetch operand, so the kernel reads one layer straight
+out of the stacked buffer and no per-layer slice is ever materialised.
+One head's block is a ``(bs, Dh)`` tile whose last two dims are whole
+array dims.  Mosaic refuses the token-major ``(P, bs, Hkv, Dh)`` layout:
+a one-head block there has a second-minor block dim of 1 against Hkv,
+and indexing the head inside an all-heads block is an unaligned sublane
+slice.  The running (m, l) stats are written out padded to a full
+128-lane row for the same reason.
 
 Early exit is block-granular: row ``b`` owns ``cache_len[b]//bs + 1``
 live blocks, and the index map *clamps* dead steps to the last live
@@ -54,7 +57,7 @@ NEG_INF = -1e30
 _STAT_LANES = 128  # f32 stats padded to one full lane row
 
 
-def _decode_body(bt_ref, cl_ref, q_ref, k_ref, v_ref,
+def _decode_body(bt_ref, cl_ref, layer_ref, q_ref, k_ref, v_ref,
                  o_ref, m_ref, l_ref,
                  acc_scr, m_scr, l_scr, *,
                  scale, bs, block_kv, npb):
@@ -80,9 +83,9 @@ def _decode_body(bt_ref, cl_ref, q_ref, k_ref, v_ref,
 
         def chunk(c, _):
             start = pl.multiple_of(c * block_kv, block_kv)
-            k = k_ref[0, 0, pl.dslice(start, block_kv), :].astype(
+            k = k_ref[0, pl.dslice(start, block_kv), :].astype(
                 jnp.float32)                                    # (bkv, dh)
-            v = v_ref[0, 0, pl.dslice(start, block_kv), :].astype(
+            v = v_ref[0, pl.dslice(start, block_kv), :].astype(
                 jnp.float32)
             sc = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -127,16 +130,21 @@ def combine_splits(acc, m, l, out_dtype):
     return (o / l_g[..., None]).reshape(B, Hkv * rep, Dh).astype(out_dtype)
 
 
-def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
+def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, layer=0,
+                        *,
                         scale: float | None = None,
                         block_kv: int | None = None,
                         n_splits: int = 1,
                         interpret: bool = False):
-    """q: (B, H, Dh); k/v_pool: (P, Hkv, bs, Dh); block_table: (B, NB);
-    cache_len: (B,) → (B, H, Dh).  Attends positions ``<= cache_len[b]``.
+    """q: (B, H, Dh); k/v_pool: (L, P, Hkv, bs, Dh), or (P, Hkv, bs, Dh)
+    as the one-layer case; block_table: (B, NB); cache_len: (B,); layer:
+    int32 scalar index into the pool's leading axis → (B, H, Dh).
+    Attends positions ``<= cache_len[b]`` of layer ``layer``.
     """
+    if k_pool.ndim == 4:
+        k_pool, v_pool = k_pool[None], v_pool[None]
     B, H, Dh = q.shape
-    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    Hkv, bs = k_pool.shape[2], k_pool.shape[3]
     NB = block_table.shape[1]
     assert H % Hkv == 0, (H, Hkv)
     rep = H // Hkv
@@ -147,25 +155,28 @@ def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
 
     qr = q.reshape(B, Hkv, rep, Dh)
 
-    def kv_index(b, h, s, j, bt_ref, cl_ref):
+    def kv_index(b, h, s, j, bt_ref, cl_ref, layer_ref):
         i = s * npb + j
         n_live = cl_ref[b] // bs + 1
         live = jnp.minimum(i, n_live - 1)          # clamp dead steps →
-        return (bt_ref[b, live], h, 0, 0)          # revisit elision, no DMA
+        return (layer_ref[0], bt_ref[b, live], h, 0, 0)  # revisit elision
 
-    def split_index(b, h, s, j, bt_ref, cl_ref):
+    def split_index(b, h, s, j, bt_ref, cl_ref, layer_ref):
         return (b, h, s, 0, 0)
+
+    kv_block = (pl.squeezed, pl.squeezed, 1, bs, Dh)
 
     grid = (B, Hkv, n_splits, npb)
     kernel = functools.partial(_decode_body, scale=scale, bs=bs,
                                block_kv=block_kv, npb=npb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                     # block_table, cache_len
+        num_scalar_prefetch=3,            # block_table, cache_len, layer
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, rep, Dh), lambda b, h, s, j, bt, cl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, Dh), kv_index),
-            pl.BlockSpec((1, 1, bs, Dh), kv_index),
+            pl.BlockSpec((1, 1, rep, Dh),
+                         lambda b, h, s, j, bt, cl, ly: (b, h, 0, 0)),
+            pl.BlockSpec(kv_block, kv_index),
+            pl.BlockSpec(kv_block, kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, rep, Dh), split_index),
@@ -190,5 +201,6 @@ def paged_decode_kernel(q, k_pool, v_pool, block_table, cache_len, *,
         ],
         interpret=interpret,
         name="paged_decode",
-    )(block_table, cache_len, qr, k_pool, v_pool)
+    )(block_table, cache_len, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      qr, k_pool, v_pool)
     return combine_splits(acc, m[..., 0], l[..., 0], q.dtype)

@@ -35,30 +35,32 @@ def _on_tpu() -> bool:
 
 
 @partial(jax.jit, static_argnames=("scale", "block_kv", "n_splits", "impl"))
-def _paged_decode_jit(q, k_pool, v_pool, block_table, cache_len, *,
+def _paged_decode_jit(q, k_pool, v_pool, block_table, cache_len, layer, *,
                       scale, block_kv, n_splits, impl):
     use_kernel = impl in ("kernel", "interpret") or (
         impl in (None, "auto") and _on_tpu())
     if use_kernel:
         return paged_decode_kernel(
-            q, k_pool, v_pool, block_table, cache_len, scale=scale,
+            q, k_pool, v_pool, block_table, cache_len, layer, scale=scale,
             block_kv=block_kv, n_splits=n_splits,
             interpret=impl == "interpret" or not _on_tpu(),
         )
     return paged_decode_ref(q, k_pool, v_pool, block_table, cache_len,
-                            scale=scale)
+                            layer, scale=scale)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_table, cache_len, *,
-                           scale=None, block_kv=None, n_splits=None,
-                           impl=None):
-    """q: (B, H, Dh); k/v_pool: (P, Hkv, bs, Dh); block_table: (B, NB)
-    int32; cache_len: (B,) int32 → (B, H, Dh), attending logical
-    positions ``<= cache_len[b]`` of each row's paged KV history."""
+def paged_decode_attention(q, k_pool, v_pool, block_table, cache_len,
+                           layer=0, *, scale=None, block_kv=None,
+                           n_splits=None, impl=None):
+    """q: (B, H, Dh); k/v_pool: (L, P, Hkv, bs, Dh), or (P, Hkv, bs, Dh)
+    as the one-layer case; block_table: (B, NB) int32; cache_len: (B,)
+    int32; layer: int32 scalar → (B, H, Dh), attending logical positions
+    ``<= cache_len[b]`` of each row's paged KV history in layer
+    ``layer`` of the pool."""
     if block_kv is None or n_splits is None:
         B, H, Dh = q.shape
-        shape = tiling.shape_key(B, H, k_pool.shape[1], Dh,
-                                 block_table.shape[1], k_pool.shape[2],
+        shape = tiling.shape_key(B, H, k_pool.shape[-3], Dh,
+                                 block_table.shape[1], k_pool.shape[-2],
                                  q.dtype)
         tuned = tuned_config("paged_decode", shape, tiling.default(shape))
         block_kv = block_kv if block_kv is not None else tuned.get(
@@ -66,5 +68,5 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, cache_len, *,
         n_splits = n_splits if n_splits is not None else tuned.get(
             "n_splits", 1)
     return _paged_decode_jit(q, k_pool, v_pool, block_table, cache_len,
-                             scale=scale, block_kv=int(block_kv),
+                             layer, scale=scale, block_kv=int(block_kv),
                              n_splits=int(n_splits), impl=impl)

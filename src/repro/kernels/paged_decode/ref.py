@@ -22,24 +22,28 @@ __all__ = ["paged_decode_ref"]
 NEG_INF = -1e30
 
 
-def paged_decode_ref(q, k_pool, v_pool, block_table, cache_len, *,
+def paged_decode_ref(q, k_pool, v_pool, block_table, cache_len, layer=0, *,
                      scale: float | None = None):
-    """q: (B, H, Dh); k/v_pool: (P, Hkv, bs, Dh); block_table: (B, NB)
-    int32; cache_len: (B,) int32 → (B, H, Dh).
+    """q: (B, H, Dh); k/v_pool: (L, P, Hkv, bs, Dh), or (P, Hkv, bs, Dh)
+    as the one-layer case; block_table: (B, NB) int32; cache_len: (B,)
+    int32; layer: int32 scalar index into the pool's leading axis →
+    (B, H, Dh).
 
     ``cache_len[b]`` is row b's highest valid logical position (the
     decode step's freshly written token), so ``cache_len[b] + 1`` keys
     are attended.  GQA: consecutive groups of ``H // Hkv`` query heads
     share one KV head.
     """
+    if k_pool.ndim == 4:
+        k_pool, v_pool = k_pool[None], v_pool[None]
     B, H, Dh = q.shape
-    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    Hkv, bs = k_pool.shape[2], k_pool.shape[3]
     NB = block_table.shape[1]
     rep = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(Dh)
 
     def logical(pool):  # (B, NB, Hkv, bs, Dh) → (B, NB·bs, Hkv, Dh)
-        return (pool[block_table].transpose(0, 1, 3, 2, 4)
+        return (pool[layer, block_table].transpose(0, 1, 3, 2, 4)
                 .reshape(B, NB * bs, Hkv, Dh).astype(jnp.float32))
 
     k, v = logical(k_pool), logical(v_pool)

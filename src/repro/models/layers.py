@@ -269,7 +269,8 @@ def blocked_attention(
     return o[:, :Sq]
 
 
-def _paged_decode_fast_path(q, k_pool, v_pool, block_table, cache_len):
+def _paged_decode_fast_path(q, k_pool, v_pool, block_table, cache_len,
+                            layer=0):
     """Dispatch the S == 1 paged decode step to the specialised kernel,
     or return ``None`` to fall through to the gather + dense path.
 
@@ -296,7 +297,7 @@ def _paged_decode_fast_path(q, k_pool, v_pool, block_table, cache_len):
     from repro.kernels.paged_decode import paged_decode_attention
 
     o = paged_decode_attention(q[:, 0], k_pool, v_pool, block_table,
-                               cache_len, impl=impls[mode])
+                               cache_len, layer, impl=impls[mode])
     return o[:, None]  # (B, 1, H, Dh)
 
 
@@ -304,12 +305,14 @@ def attention_block(
     x, p, cfg, *,
     positions,
     mask_kind: str,
-    cache=None,          # (k_cache, v_cache): (B, Smax, Hkv, Dh) or None,
-    #                      or a paged pool {"k_pool","v_pool"}: (P, Hkv, bs, Dh)
+    cache=None,          # (k_cache, v_cache): (B, Smax, Hkv, Dh) or None, or
+    #                      the stacked paged pool {"k_pool","v_pool"}:
+    #                      (n_scan, P, Hkv, bs, Dh)
     cache_len=None,      # int32 scalar OR per-row (B,) vector: cache fill
     kv_source=None,      # cross-attention memory (B, Sm, D)
     pos_offset=None,     # (B,) left-padding per row (ragged prompts)
     block_table=None,    # (B, NB) logical→physical block map (paged cache)
+    layer=None,          # int32 scalar: this layer's index in the paged pool
 ):
     """Full attention sublayer: projections + RoPE + blocked attention.
 
@@ -320,10 +323,12 @@ def attention_block(
     ``cache_len`` may be a per-row vector — decode slots at different fill
     levels write their new KV at per-row offsets (continuous batching).
     With a paged cache, K/V live in a fixed-size block pool indexed through
-    ``block_table``; the step scatters the new tokens' KV into their blocks
-    and attends either via the decode-specialised paged kernel (S == 1,
-    ``REPRO_PAGED_DECODE``) or over the gathered logical view (fallback,
-    and the S > 1 chunked-prefill path).
+    ``block_table``; the pool stacks every layer, and ``layer`` picks this
+    one.  The step scatters the new tokens' KV into their blocks of that
+    layer, in place, and attends either via the decode-specialised paged
+    kernel (S == 1, ``REPRO_PAGED_DECODE``), which reads the layer out of
+    the stacked pool, or over the gathered logical view (fallback, and
+    the S > 1 chunked-prefill path).
     """
     B, S, D = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
@@ -352,22 +357,29 @@ def attention_block(
         elif "k_pool" in cache:
             # Paged path: scatter the S new tokens' KV into their blocks.
             # Slot i's token t lands at logical position cache_len[i] + t =
-            # physical (block_table[i, pos//bs], :, pos % bs) of the
-            # head-major pool (P, Hkv, bs, Dh).  S == 1 is the
-            # decode step; S > 1 is a chunked-prefill chunk riding the same
-            # path (right-padded rows route their junk positions to block
-            # indices past the row's live table entries — the caller sizes
-            # the table so those columns exist and point at scratch).
+            # physical (layer, block_table[i, pos//bs], :, pos % bs) of the
+            # stacked head-major pool (n_scan, P, Hkv, bs, Dh).  The pool is
+            # the layer scan's carry, so this one scatter updates the
+            # donated buffer in place; no per-layer slice is taken.  S == 1
+            # is the decode step; S > 1 is a chunked-prefill chunk riding
+            # the same path (right-padded rows route their junk positions
+            # to block indices past the row's live table entries — the
+            # caller sizes the table so those columns exist and point at
+            # scratch).
             kp, vp = cache["k_pool"], cache["v_pool"]
-            bs_blk = kp.shape[2]
+            bs_blk = kp.shape[3]
             cl = (cache_len if jnp.ndim(cache_len)
                   else jnp.full((B,), cache_len, jnp.int32))
             tok_pos = cl[:, None] + jnp.arange(S)            # (B, S)
             blk = tok_pos // bs_blk
             off = tok_pos % bs_blk
             phys = block_table[jnp.arange(B)[:, None], blk]  # (B, S)
-            kp = kp.at[phys, :, off].set(k.astype(kp.dtype))  # (B,S,Hkv,Dh)
-            vp = vp.at[phys, :, off].set(v.astype(vp.dtype))
+            # One (Dh,) row per (token, head): a window of the minor dim
+            # alone keeps the pool's default layout through the scatter,
+            # where an (Hkv, Dh) window makes XLA re-lay the whole pool.
+            at = (layer, phys[..., None], jnp.arange(Hkv), off[..., None])
+            kp = kp.at[at].set(k.astype(kp.dtype))           # (B,S,Hkv,Dh)
+            vp = vp.at[at].set(v.astype(vp.dtype))
             new_cache = {"k_pool": kp, "v_pool": vp}
             kv_len = cl + S - 1                              # (B,)
             if S == 1 and mask_kind == "causal":
@@ -376,15 +388,16 @@ def attention_block(
                 # block-granular early exit at each row's last live block.
                 # REPRO_PAGED_DECODE picks the impl; the gather fallback
                 # below stays the CPU default and exactness oracle.
-                o = _paged_decode_fast_path(q, kp, vp, block_table, kv_len)
+                o = _paged_decode_fast_path(q, kp, vp, block_table, kv_len,
+                                            layer)
                 if o is not None:
                     out = jnp.einsum("bshk,hkd->bsd", o,
                                      p["wo"].reshape(H, Dh, D))
                     return out, new_cache
             # (B, NB, Hkv, bs, Dh) → logical view (B, NB·bs, Hkv, Dh)
-            k_full = kp[block_table].transpose(0, 1, 3, 2, 4).reshape(
+            k_full = kp[layer, block_table].transpose(0, 1, 3, 2, 4).reshape(
                 B, -1, Hkv, Dh)
-            v_full = vp[block_table].transpose(0, 1, 3, 2, 4).reshape(
+            v_full = vp[layer, block_table].transpose(0, 1, 3, 2, 4).reshape(
                 B, -1, Hkv, Dh)
             k_pos = jnp.arange(k_full.shape[1])
         else:

@@ -232,13 +232,26 @@ def _run_stack(
     attention masks the pad slots, SSD mixers treat them as zero-input
     unit-decay steps.  ``block_table`` routes attention K/V through a
     paged block pool (see :func:`paged_cache_shapes`).
+
+    A paged pool rides in the scan's carry, whole, and the body gets its
+    layer index: each layer scatters its new tokens into the stacked pool
+    in place and the kernel reads its layer from it.  As ``xs``/``ys``
+    the scan would slice every layer's pool out and stack it back, which
+    copies the whole pool on every step.  Every other cache (dense K/V,
+    SSD state) is sliced per layer through ``xs``/``ys``.
     """
     # per-row validity for SSD mixers: pad positions carry negatives
     ssm_valid = positions >= 0 if positions.ndim == 2 else None
+    paged = cache is not None and all(
+        "k_pool" in sub for sub in cache.values())
 
     def body(carry, inp):
-        x, aux = carry
-        lp, lc = inp if cache is not None else (inp, None)
+        x, aux, pool = carry
+        if paged:
+            (lp, layer), lc = inp, pool       # the whole stacked pool
+        else:
+            lp, lc = inp if cache is not None else (inp, None)
+            layer = None
         new_lc = {} if (want_cache or cache is not None) else None
         for i, (mixer, ffn) in enumerate(plan):
             sp = lp[f"sub{i}"]
@@ -249,6 +262,7 @@ def _run_stack(
                     h, sp["attn"], cfg, positions=positions, mask_kind=mask_kind,
                     cache=sc, cache_len=cache_len,
                     pos_offset=pos_offset, block_table=block_table,
+                    layer=layer,
                 )
                 x = x + mo
                 if mixer == "attn_cross":
@@ -280,12 +294,21 @@ def _run_stack(
 
         if _L.SP_HINT:
             x = _maybe_constrain(x, "dp", "model", None)
-        return (x, aux), new_lc
+        if paged:
+            return (x, aux, new_lc), None
+        return (x, aux, None), new_lc
 
     if remat:
         body = jax.checkpoint(body)
+    if paged:
+        n_scan = jax.tree.leaves(blocks)[0].shape[0]
+        (x, aux, new_cache), _ = jax.lax.scan(
+            body, (x, jnp.float32(0.0), cache),
+            (blocks, jnp.arange(n_scan, dtype=jnp.int32)))
+        return x, aux, new_cache
     xs = (blocks, cache) if cache is not None else blocks
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
+    (x, aux, _), new_cache = jax.lax.scan(
+        body, (x, jnp.float32(0.0), None), xs)
     return x, aux, new_cache
 
 

@@ -27,13 +27,15 @@ def _case(B, H, Hkv, Dh, NB, bs, dtype, cache_lens, seed=0):
     return q, kp, vp, bt, cl
 
 
-def _gather_oracle(q, kp, vp, bt, cl):
+def _gather_oracle(q, kp, vp, bt, cl, layer=None):
     """The layers.py fallback, verbatim semantics: gather the logical
-    view, dense causal attention with q at position cache_len."""
+    view (of layer ``layer`` of a stacked pool), dense causal attention
+    with q at position cache_len."""
     B, H, Dh = q.shape
-    Hkv = kp.shape[1]
-    k = kp[bt].transpose(0, 1, 3, 2, 4).reshape(B, -1, Hkv, Dh)
-    v = vp[bt].transpose(0, 1, 3, 2, 4).reshape(B, -1, Hkv, Dh)
+    Hkv = kp.shape[-3]
+    at = bt if layer is None else (layer, bt)
+    k = kp[at].transpose(0, 1, 3, 2, 4).reshape(B, -1, Hkv, Dh)
+    v = vp[at].transpose(0, 1, 3, 2, 4).reshape(B, -1, Hkv, Dh)
     o = blocked_attention(
         q[:, None], k, v,
         q_positions=cl[:, None], k_positions=jnp.arange(k.shape[1]),
@@ -59,6 +61,34 @@ def test_ref_kernel_gather_agree(dtype, H, Hkv, n_splits):
     np.testing.assert_allclose(np.asarray(gat, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("n_splits", [1, 2, 4])
+def test_stacked_pool_layer_matches_slice(layer, n_splits):
+    """A 3-layer pool read at ``layer`` gives exactly what the same call
+    gives on the slice ``pool[layer]``, on every path."""
+    B, H, Hkv, Dh, NB, bs = 4, 8, 2, 64, 4, 16
+    rng = np.random.default_rng(layer)
+    q, *_, bt, cl = _case(B, H, Hkv, Dh, NB, bs, "bfloat16",
+                          [bs - 1, bs, 2 * bs, 0], seed=layer)
+    shape = (3, B * NB + 1, Hkv, bs, Dh)
+    kp = jnp.asarray(rng.standard_normal(shape), "bfloat16")
+    vp = jnp.asarray(rng.standard_normal(shape), "bfloat16")
+    ly = jnp.int32(layer)
+    pairs = [
+        (paged_decode_kernel(q, kp, vp, bt, cl, ly, n_splits=n_splits,
+                             interpret=True),
+         paged_decode_kernel(q, kp[layer], vp[layer], bt, cl,
+                             n_splits=n_splits, interpret=True)),
+        (paged_decode_ref(q, kp, vp, bt, cl, ly),
+         paged_decode_ref(q, kp[layer], vp[layer], bt, cl)),
+        (_gather_oracle(q, kp, vp, bt, cl, ly),
+         _gather_oracle(q, kp[layer], vp[layer], bt, cl)),
+    ]
+    for stacked, sliced in pairs:
+        np.testing.assert_array_equal(np.asarray(stacked, np.float32),
+                                      np.asarray(sliced, np.float32))
 
 
 @pytest.mark.parametrize("block_kv", [8, 16])
@@ -190,3 +220,59 @@ def test_unknown_paged_decode_mode_raises(monkeypatch):
     monkeypatch.setenv("REPRO_PAGED_DECODE", "kernal")
     with pytest.raises(ValueError, match="REPRO_PAGED_DECODE"):
         _paged_decode_fast_path(q[:, None], kp, vp, bt, cl)
+
+
+def _layer_scan_routes(cfg, cache, batch):
+    """Shapes of the layer scan's (consts, carry, xs, ys) in decode_step's
+    jaxpr."""
+    import jax
+
+    from repro.models import transformer as T
+
+    params = T.param_specs(cfg)
+    jaxpr = jax.make_jaxpr(lambda p, c, b: T.decode_step(p, c, b, cfg))(
+        params, cache, batch).jaxpr
+    n_scan = T.layer_plan(cfg)[0]
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"
+              and e.params["length"] == n_scan]
+    nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+    shapes = [tuple(v.aval.shape) for v in eqn.invars]
+    return {"consts": shapes[:nc], "carry": shapes[nc:nc + nk],
+            "xs": shapes[nc + nk:],
+            "ys": [tuple(v.aval.shape) for v in eqn.outvars[nk:]]}
+
+
+@pytest.mark.parametrize("kind,S", [("paged", 1), ("paged", 8),
+                                    ("dense", 1)])
+def test_layer_scan_cache_route(kind, S):
+    """The paged pool rides decode_step's layer scan as carry only: no
+    stacked or per-layer pool in xs, ys or consts, so the scan never
+    slices a layer's pool out and stacks it back.  A dense cache keeps
+    the xs/ys route, one layer's cache per scan step."""
+    import jax
+
+    from repro.configs.registry import get_config
+    from repro.models import transformer as T
+
+    cfg = get_config("qwen3-4b", reduced=True)
+    B, bs, n_blocks, NB = 2, 16, 7, 3
+    i32 = jnp.int32
+    if kind == "paged":
+        cache = jax.eval_shape(lambda: T.init_paged_cache(cfg, n_blocks, bs))
+        batch = {"tokens": jax.ShapeDtypeStruct((B, S), i32),
+                 "cache_len": jax.ShapeDtypeStruct((B,), i32),
+                 "block_table": jax.ShapeDtypeStruct((B, NB), i32)}
+    else:
+        cache = T.cache_specs(cfg, B, 32)
+        batch = {"tokens": jax.ShapeDtypeStruct((B, S), i32),
+                 "cache_len": jax.ShapeDtypeStruct((), i32)}
+    stacked = {tuple(leaf.shape) for leaf in jax.tree.leaves(cache)}
+    per_layer = {s[1:] for s in stacked}
+    routes = _layer_scan_routes(cfg, cache, batch)
+    if kind == "paged":
+        assert stacked <= set(routes["carry"])
+        for where in ("consts", "xs", "ys"):
+            assert not (stacked | per_layer) & set(routes[where]), where
+    else:
+        assert stacked <= set(routes["xs"]) and stacked <= set(routes["ys"])
+        assert not (stacked | per_layer) & set(routes["carry"])

@@ -53,3 +53,48 @@ def test_paged_decode_compiles_for_v5e(one_chip, arch, n_splits):
                         spec((B, NB), jnp.int32),
                         spec((B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("S", [1, 256])
+def test_serve_step_updates_pool_in_place_for_v5e(one_chip, monkeypatch, S):
+    """The donated decode (S == 1, the paged_decode kernel) and chunk
+    (S > 1, the gather path) steps at qwen3-4b's widths, two layers:
+    the compiled program keeps the pool in place.  No copy, dynamic
+    slice or dynamic update slice of the pool's shape, stacked or one
+    layer's, and less scratch than one pool."""
+    import dataclasses
+    import re
+
+    from repro.kernels.paged_decode import ops
+    from repro.models import transformer as T
+
+    # code that asks for the backend sees the CPU here: steer it to the
+    # kernel as the chip would take it
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setenv("REPRO_PAGED_DECODE", "kernel")
+    cfg = dataclasses.replace(get_config("qwen3-4b"), n_layers=2)
+    B, n_blocks, bs, NB = 4 if S == 1 else 1, 17, 256, 4
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda s: spec(s.shape, s.dtype), T.param_specs(cfg))
+    pool = jax.tree.map(lambda s: spec(s, T.DTYPE),
+                        T.paged_cache_shapes(cfg, n_blocks, bs),
+                        is_leaf=lambda x: isinstance(x, tuple))
+    batch = {"tokens": spec((B, S), jnp.int32),
+             "cache_len": spec((B,), jnp.int32),
+             "block_table": spec((B, NB), jnp.int32)}
+    compiled = jax.jit(lambda p, c, b: T.decode_step(p, c, b, cfg),
+                       donate_argnums=(1,)).lower(params, pool, batch).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (S == 1)
+    leaf = T.paged_cache_shapes(cfg, n_blocks, bs)["sub0"]["k_pool"]
+    dims = "|".join(re.escape(",".join(map(str, s))) for s in (leaf, leaf[1:]))
+    # a pool-shaped instruction, or fusion, that copies or slices
+    moves = [m for m in re.findall(
+        rf"%(\S+) = bf16\[(?:{dims})\]\S* ([\w-]+)\(", text)
+        if re.search(r"copy|dynamic", " ".join(m))]
+    assert not moves, moves
+    pool_bytes = 2 * 2 * leaf[0] * leaf[1] * leaf[2] * leaf[3] * leaf[4]
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
