@@ -27,14 +27,14 @@ sys.path.insert(0, HERE)
 import run as runner  # noqa: E402
 
 
-def _serving_control(readings: dict):
+def _serving_control(readings: dict, R):
     """Wrap the served-token check so that it also reads the control: at
     every position of the same sample, the gap (under the float32
-    reference) of the token the float8 reference puts first."""
+    reference ``R``, the model family's) of the token the float8
+    reference puts first."""
     import numpy as np
 
     import serving
-    from reference import transformer as R
 
     check = serving.check_served
 
@@ -53,10 +53,7 @@ def _serving_control(readings: dict):
     return wrapped
 
 
-def _sample_spy(readings: dict):
-    import serving
-    from reference import transformer as R
-
+def _sample_spy(readings: dict, R):
     gaps = R.served_gaps
 
     def spy(cfg, quant, params, prompt, served, other=None):
@@ -121,13 +118,14 @@ def main(argv=None) -> int:
             undo = _plant(args.fault)
         if args.control:
             runner._paths(HERE)
+            import cells
             import serving
-            from reference import transformer as R
 
+            R = cells.load_cell(args.workload, HERE).family.reference
             patches.append((serving, "check_served", serving.check_served))
             patches.append((R, "served_gaps", R.served_gaps))
-            R.served_gaps = _sample_spy(readings)
-            serving.check_served = _serving_control(readings)
+            R.served_gaps = _sample_spy(readings, R)
+            serving.check_served = _serving_control(readings, R)
         res = runner.run_cell(
             ["--workload", args.workload, "--seed", str(seed), "--seconds",
              str(args.seconds)], t_process=time.perf_counter())

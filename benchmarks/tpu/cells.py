@@ -1,20 +1,36 @@
 """Finds a cell's pieces by name: ``workloads/<cell>.json``,
-``configs/<config>.json``, ``drivers/<kind>.py``, ``metrics/<metric>.py``
-(or, for a metric split by a suffix such as ``.chat``, the reader of the
-unsplit name) and the cell's metric entries in ``BENCHMARK.json``.  Adding a cell, a
-configuration, a driver or a per-layer metric is adding files: nothing
-here names one.
+``configs/<config>.json``, the model family that the configuration names
+under ``"family"``, ``drivers/<kind>.py``, ``metrics/<metric>.py`` (or,
+for a metric split by a suffix such as ``.chat``, the reader of the
+unsplit name) and the cell's metric entries in ``BENCHMARK.json``.
+
+A model family is three files of the same name:
+
+- ``families/<family>.py``: ``program_config(cfg)``, the configuration's
+  keys mapped onto the program's ``ArchConfig`` (refusing a registry
+  entry the family does not cover); ``FIELDS``, the keys it copies as
+  they are, ``{file key: ArchConfig field}``; and ``shapes(cfg)``, the
+  weights tree as ``{path: (shape, fan_in or None for a norm)}``;
+- ``reference/<family>.py``: the plain float32 reference and its
+  control (``served_gaps``, ``forward_hidden``, ``loss_and_grads``);
+- ``counts/<family>.py``: operations and bytes from the sizes alone
+  (``forward_flops``, ``paged_decode_cost``, ``causal_pairs``).
+
+Adding a cell, a configuration, a model family, a driver or a per-layer
+metric is adding files: nothing here names one.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
 
-__all__ = ["HERE", "Cell", "load_cell", "list_cells", "load_module",
-           "metric_reader", "program_config", "peaks"]
+__all__ = ["HERE", "Cell", "Family", "load_cell", "list_cells",
+           "load_module", "family", "metric_reader", "program_config",
+           "peaks"]
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -35,11 +51,21 @@ def _checkout(root: str) -> str:
     return d
 
 
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """A model family's three modules (see the module docstring)."""
+    name: str
+    arch: object              # families/<family>.py
+    reference: object         # reference/<family>.py
+    counts: object            # counts/<family>.py
+
+
 @dataclasses.dataclass
 class Cell:
     name: str
     workload: dict            # workloads/<cell>.json
     config: dict              # configs/<config>.json
+    family: Family            # the model family the configuration names
     end_to_end: list          # BENCHMARK.json entries this cell reports
     per_layer: list
     root: str                 # the benchmark's directory
@@ -68,14 +94,18 @@ def load_cell(name: str, root: str = HERE) -> Cell:
     wl = _json(path)
     if wl["name"] != name:
         raise ValueError(f"{path} names itself {wl['name']!r}")
-    cfg = _json(os.path.join(root, "configs", f"{wl['config']}.json"))
+    cfg_path = os.path.join(root, "configs", f"{wl['config']}.json")
+    cfg = _json(cfg_path)
+    if "family" not in cfg:
+        raise KeyError(f"{cfg_path} names no model family (key 'family')")
+    fam = family(cfg, root)
     checkout = _checkout(root)
     bench = _json(os.path.join(checkout, "BENCHMARK.json"))
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _reports(m, name, names)]
-    return Cell(name, wl, cfg, e2e, per_layer, root, checkout)
+    return Cell(name, wl, cfg, fam, e2e, per_layer, root, checkout)
 
 
 def load_module(root: str, kind: str, name: str):
@@ -86,6 +116,26 @@ def load_module(root: str, kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def family(cfg: dict, root: str = HERE) -> Family:
+    """The model family that configuration ``cfg`` names, from ``root``."""
+    return _family(os.path.abspath(root), cfg["family"])
+
+
+_FAMILY_PARTS = ("families", "reference", "counts")
+
+
+@functools.lru_cache(maxsize=None)
+def _family(root: str, name: str) -> Family:
+    # one module object per file: what a test or calibrate.py patches in a
+    # family's module is what the run then calls
+    for part in _FAMILY_PARTS:
+        path = os.path.join(root, part, f"{name}.py")
+        if not os.path.exists(path):
+            raise FileNotFoundError(f"model family {name!r} has no {path}")
+    return Family(name, *(load_module(root, part, name)
+                          for part in _FAMILY_PARTS))
 
 
 def metric_reader(root: str, name: str):
@@ -104,27 +154,7 @@ def peaks(device_kind: str, root: str = HERE) -> dict:
     return table[device_kind]
 
 
-# configuration file key → the program's ArchConfig field
-_FIELDS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab", "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings", "qk_norm": "qk_norm",
-    "attention_bias": "attn_bias",
-}
-
-
-def program_config(cfg: dict):
-    """The program's ArchConfig for a configuration file: its registry
-    entry with every size set from the file."""
-    from repro.configs.registry import get_config
-
-    base = get_config(cfg["arch"])
-    if base.family != "dense" or base.n_experts or base.attention != "full":
-        raise ValueError(f"{cfg['arch']} is not a dense full-attention LM")
-    if cfg["torch_dtype"] != base.dtype:
-        raise ValueError(f"{cfg['name']} states {cfg['torch_dtype']}, the "
-                         f"program runs {base.dtype}")
-    return dataclasses.replace(
-        base, **{f: cfg[k] for k, f in _FIELDS.items()})
+def program_config(cfg: dict, root: str = HERE):
+    """The program's ArchConfig for a configuration file, as its model
+    family maps it."""
+    return family(cfg, root).arch.program_config(cfg)

@@ -35,12 +35,12 @@ def run(run: Run) -> None:
 
     for c in range(len(queues)):
         current.append(send(c))
-    log = serving.StepLog()
+    log = serving.StepLog(run.cell.family.counts)
     while any(r.n_generated == 0 for r in current):
         log.step(engine, current)
     say("filled", {"slots": engine.n_running, "steps": log.steps})
 
-    log = serving.StepLog()
+    log = serving.StepLog(run.cell.family.counts)
     with run.window():
         t0 = time.perf_counter()
         t_end = t0 + run.window_seconds

@@ -16,11 +16,12 @@ import traffic
 from harness import Run, nearest_rank, say, span
 
 
-def serve(engine, specs, seconds: float, drain_s: float,
+def serve(engine, specs, seconds: float, drain_s: float, counts,
           window=contextlib.nullcontext) -> dict:
     """Submit ``specs`` as they fall due over ``seconds`` while stepping
-    the engine, inside ``window()``; then drain for at most ``drain_s``."""
-    log = serving.StepLog()
+    the engine, inside ``window()``; then drain for at most ``drain_s``.
+    ``counts`` is the model family's counts module (``StepLog``)."""
+    log = serving.StepLog(counts)
     sent, late, inflight = [], [], []
     with window():
         t0 = time.perf_counter()
@@ -46,7 +47,7 @@ def serve(engine, specs, seconds: float, drain_s: float,
             inflight = [r for r in inflight if not r.terminal]
         t1 = time.perf_counter()
     backlog, queued = len(inflight), len(engine.queue)
-    drain = serving.StepLog()
+    drain = serving.StepLog(counts)
     drain_end = time.perf_counter() + drain_s
     while inflight and time.perf_counter() < drain_end:
         drain.step(engine, inflight)
@@ -70,7 +71,7 @@ def run(run: Run) -> None:
                                               for s in specs))})
 
     out = serve(engine, specs, run.window_seconds, wl["drain_s"],
-                run.window)
+                run.cell.family.counts, run.window)
     sent, late, window_log, log = (out["sent"], out["late"], out["log"],
                                    out["drain_log"])
     inflight = out["inflight"]

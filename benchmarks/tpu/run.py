@@ -99,7 +99,7 @@ def run_cell(argv=None, *, root: str = HERE, allow_cpu: bool = False,
     os.makedirs(out_dir)
     run = Run(cell=cell, seed=args.seed, seconds=args.seconds,
               trace=bool(args.trace), t_process=t_process, out_dir=out_dir,
-              program=cells.program_config(cell.config),
+              program=cells.program_config(cell.config, root),
               peaks=cells.peaks(dev.device_kind, root)
               if dev.platform == "tpu" else {"peak_flops_bf16": 1.0,
                                              "hbm_bytes_per_s": 1.0},

@@ -19,7 +19,6 @@ import numpy as np
 
 import weights
 from harness import Run, say, span
-from reference import transformer as R
 
 __all__ = ["build_engine", "warm_up", "StepLog", "check_served",
            "request_from", "shape_keys"]
@@ -33,15 +32,17 @@ def _pow2(n: int) -> int:
 
 
 def build_engine(run: Run):
-    """The program's engine on weights made from the seed."""
+    """The program's engine on weights made from the seed, in the layout
+    of the configuration's model family."""
     from repro.models import transformer as T
     from repro.serve import ContinuousConfig, ContinuousEngine
 
-    params = weights.make(run.cfg, run.seed)
+    params = weights.make(run.cfg, run.seed, run.cell.root)
     want = jax.tree.map(lambda s: (s.shape, s.dtype), T.param_specs(run.program))
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     if want != got:
-        raise ValueError("weights.py's layout is not the program's")
+        raise ValueError(f"model family {run.cell.family.name!r} lays "
+                         f"out weights that are not the program's")
     e = run.wl["engine"]
     engine = ContinuousEngine(run.program, params, ContinuousConfig(
         max_len=e["max_len"], n_slots=e["n_slots"],
@@ -108,10 +109,12 @@ def request_from(spec, t_due: float | None = None):
 class StepLog:
     """Per engine step: which in-flight requests decoded, and over how
     many live keys (prompt + generated so far); prompt tokens whose
-    prefill completed; first tokens.  Read from the requests' own token
-    counts, so it costs no device time."""
+    prefill completed, and their query-key pairs as the model family's
+    ``counts`` reckons them; first tokens.  Read from the requests' own
+    token counts, so it costs no device time."""
 
-    def __init__(self):
+    def __init__(self, counts):
+        self.counts = counts
         self.decode_tokens = 0        # tokens made by decode steps
         self.first_tokens = 0         # tokens made by the end of a prefill
         self.prefill_tokens = 0       # prompt tokens of completed prefills
@@ -121,8 +124,6 @@ class StepLog:
         self.live = []                # (t, live keys, rows) per decode step
 
     def step(self, engine, inflight: list) -> None:
-        from counts.transformer import causal_pairs
-
         before = [r.n_generated for r in inflight]
         with span("step"):
             engine.step()
@@ -136,7 +137,7 @@ class StepLog:
             if n0 == 0:
                 self.first_tokens += 1
                 self.prefill_tokens += r.prompt_len
-                self.prefill_pairs += causal_pairs(r.prompt_len)
+                self.prefill_pairs += self.counts.causal_pairs(r.prompt_len)
             if decoded:
                 keys = r.prompt_len + n1 - 1
                 live += keys
@@ -149,9 +150,10 @@ class StepLog:
 
 def check_served(run: Run, sent: list, params) -> None:
     """Compare a seeded sample of the requests the run finished, with the
-    longest, against the plain reference: the widest gap by which a
-    served token's logit lies below the reference's best at its
-    position."""
+    longest, against the plain reference of the configuration's model
+    family: the widest gap by which a served token's logit lies below
+    the reference's best at its position."""
+    R = run.cell.family.reference
     chk = run.wl["check"]
     served_reqs = [r for r in sent
                    if r.state.value == "finished" and r.n_generated > 0]

@@ -57,7 +57,8 @@ def main(argv=None) -> int:
         specs = traffic.open_loop(mix, args.seed, args.seconds,
                                   cell.config["vocab_size"], e["max_len"],
                                   rate=rate)
-        out = drv.serve(engine, specs, args.seconds, 120.0)
+        out = drv.serve(engine, specs, args.seconds, 120.0,
+                        cell.family.counts)
         queued = out["queued"]
         sent = out["sent"]
         ok = [r for r in sent if r.state.value == "finished"]

@@ -4,13 +4,12 @@ fail each cell's check at a size a test run holds.  The same readings
 at the cells' own sizes on the chip set the limits (``calibrate.py``)."""
 
 
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import cells
 import tiny
 import weights
-from reference import transformer as R
 
 
 def _limits(cell):
@@ -22,6 +21,7 @@ def _limits(cell):
     ("qwen3-4b.chat_poisson", "tiny-qwen3")])
 def test_serving_control_fails(cell, config):
     cfg = tiny.CONFIGS[config]
+    R = cells.family(cfg).reference
     params = weights.make(cfg, 2**31 + 17)
     rng = np.random.default_rng(17)
     worst = 0.0

@@ -1,5 +1,6 @@
 """The plain reference against the program's own forward pass and loss,
-at a small size on the CPU, for both model families."""
+at a small size on the CPU, for both tiny configurations, each reached
+through the model family it names."""
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +10,6 @@ import pytest
 import cells
 import tiny
 import weights
-from reference import transformer as R
 
 
 @pytest.fixture(params=sorted(tiny.CONFIGS))
@@ -21,7 +21,7 @@ def model(request):
     acfg = cells.program_config(cfg)
     spec = jax.tree.map(lambda s: (s.shape, s.dtype), T.param_specs(acfg))
     assert jax.tree.map(lambda a: (a.shape, a.dtype), params) == spec
-    return cfg, acfg, params, T
+    return cfg, acfg, params, T, cells.family(cfg).reference
 
 
 def _tokens(cfg, shape, seed=0):
@@ -30,7 +30,7 @@ def _tokens(cfg, shape, seed=0):
 
 
 def test_forward_logits_agree(model):
-    cfg, acfg, params, T = model
+    cfg, acfg, params, T, R = model
     tok = _tokens(cfg, (1, 256))
     prog, _ = T.forward(params, {"tokens": jnp.asarray(tok)}, acfg)
     prog = np.asarray(prog.astype(jnp.float32))[0]
@@ -49,7 +49,7 @@ def test_forward_logits_agree(model):
 
 
 def test_served_gaps_teacher_forced(model):
-    cfg, acfg, params, T = model
+    cfg, acfg, params, T, R = model
     tok = _tokens(cfg, (1, 200), seed=1)[0]
     prog, _ = T.forward(params, {"tokens": jnp.asarray(tok[None])}, acfg)
     pick = np.asarray(prog[0].astype(jnp.float32)).argmax(-1)[99:199]
@@ -63,7 +63,7 @@ def test_served_gaps_teacher_forced(model):
 
 
 def test_loss_and_gradient_agree(model):
-    cfg, acfg, params, T = model
+    cfg, acfg, params, T, R = model
     tok = _tokens(cfg, (2, 256), seed=2)
     prog, grads = jax.value_and_grad(
         lambda p: T.loss_fn(p, {"tokens": jnp.asarray(tok)}, acfg)[0])(params)
@@ -76,7 +76,7 @@ def test_loss_and_gradient_agree(model):
 
 
 def test_control_is_the_reference_in_float8(model):
-    cfg, acfg, params, T = model
+    cfg, acfg, params, T, R = model
     tok = _tokens(cfg, (1, 256), seed=3)[0]
     _, _, pick8 = R.served_gaps(cfg, "fp8", params, tok[:100], tok[100:])
     _, g8, _ = R.served_gaps(cfg, "float32", params, tok[:100], tok[100:],

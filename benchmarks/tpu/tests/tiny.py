@@ -1,6 +1,7 @@
 """A small copy of the benchmark for tests on the CPU: the real harness
 files, the real ``BENCHMARK.json`` with tiny cells added, and tiny
-configurations of both model families, in a checkout of its own whose
+configurations of InternLM2 and Qwen3 (the ``transformer`` family), in a
+checkout of its own whose
 ``src`` is the program's."""
 
 from __future__ import annotations
@@ -19,10 +20,10 @@ SMALL = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
 
 CONFIGS = {
     "tiny-internlm2": dict(SMALL, name="tiny-internlm2", arch="internlm2-1.8b",
-                           rms_norm_eps=1e-6, rope_theta=1e6,
+                           family="transformer", rms_norm_eps=1e-6, rope_theta=1e6,
                            tie_word_embeddings=False, qk_norm=False),
     "tiny-qwen3": dict(SMALL, name="tiny-qwen3", arch="qwen3-4b",
-                       rms_norm_eps=1e-6, rope_theta=1e6,
+                       family="transformer", rms_norm_eps=1e-6, rope_theta=1e6,
                        tie_word_embeddings=True, qk_norm=True),
 }
 
