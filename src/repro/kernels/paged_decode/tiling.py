@@ -1,10 +1,11 @@
 """Tiling search space + static cost model for paged decode attention.
 
 Executable form of the traffic story in ``kernel.py``'s docstring.  Grid
-= (B, Hkv, n_splits, NB/n_splits); costs are evaluated at the pool's
-steady state — rows half full (``ctx = NB·bs/2``) — because that is what
-a continuously batched serve loop actually runs at, not the worst-case
-full table the gather fallback always pays for:
+= (B, Hkv/G, n_splits, NB/n_splits), G = :func:`head_group`; costs are
+evaluated at the pool's steady state — rows half full
+(``ctx = NB·bs/2``) — because that is what a continuously batched serve
+loop actually runs at, not the worst-case full table the gather fallback
+always pays for:
 
 * ``block_kv`` — inner ``fori_loop`` chunk inside one pool block; wider
   chunks cut loop trips and fill MXU columns at 4·rep·bkv extra f32
@@ -12,11 +13,11 @@ full table the gather fallback always pays for:
   which is the structural half of the serve_kv ⇄ paged_decode joint
   resolution (serve_kv's cost model is the other half — it prices each
   candidate pool block through :func:`cost` at this model's default).
-* ``n_splits`` — flash-decode KV-axis parallelism.  A single query row
-  exposes only ``rep = H/Hkv`` MXU rows, so per-core utilisation cannot
-  improve with context; splits instead let the two TensorCores
-  (MegaCore) chew disjoint halves of the live blocks, at the price of
-  f32 partial (acc, m, l) traffic and a combine pass.
+* ``n_splits`` — flash-decode KV-axis splits, at the price of f32
+  partial (acc, m, l) traffic and a combine pass.  They shorten no
+  sequenced chain on a chip with one TensorCore, as the v5e has: there
+  every split count above 1 ran slower than 1 at the serving cells'
+  shapes, so the model prices splits by their traffic alone.
 
 :func:`gather_cost` models the XLA gather fallback at the same shape —
 three full passes over the ``NB·bs`` logical view regardless of
@@ -26,6 +27,7 @@ three full passes over the ``NB·bs`` logical view regardless of
 from __future__ import annotations
 
 from repro.kernels.autotune import (
+    VMEM_BYTES,
     KernelCost,
     TilingModel,
     bytes_per_element,
@@ -33,20 +35,35 @@ from repro.kernels.autotune import (
     register_tiling,
 )
 
-__all__ = ["shape_key", "candidates", "cost", "default", "gather_cost"]
+__all__ = ["shape_key", "candidates", "cost", "default", "gather_cost",
+           "head_group"]
 
 _BLOCK_SEEDS = (16, 32, 64, 128, 256, 512)
 _SPLIT_SEEDS = (1, 2, 4, 8)
 
-# TensorCores per chip sharing HBM: n_splits > 1 buys parallel grid-step
-# sequencing up to this factor (crude — models MegaCore as perfectly
-# splitting the sequenced-step chain, nothing else).
-_MEGACORE = 2
+# Share of the scoped VMEM that one grid step's double-buffered K and V
+# blocks may take; q, the f32 partials and Mosaic's own scratch get the
+# rest.
+_KV_VMEM_SHARE = 0.5
+
+
+def head_group(Hkv, bs, Dh, dtype) -> int:
+    """KV heads one grid step reads (G): the largest divisor of ``Hkv``
+    whose double-buffered ``(G, bs, Dh)`` K and V blocks fit
+    ``_KV_VMEM_SHARE`` of the scoped VMEM; 1 if not even one head fits."""
+    per_head = 2 * 2 * bs * Dh * bytes_per_element(dtype)
+    budget = _KV_VMEM_SHARE * VMEM_BYTES
+    return max((g for g in range(1, Hkv + 1)
+                if Hkv % g == 0 and g * per_head <= budget), default=1)
 
 
 def shape_key(B, H, Hkv, Dh, NB, bs, dtype) -> dict:
+    # G is implied by the rest; it is in the key so that tiles tuned for
+    # the one-head-per-step kernel, which had no G, are never served to
+    # this one.
     return {"B": int(B), "H": int(H), "Hkv": int(Hkv), "Dh": int(Dh),
-            "NB": int(NB), "bs": int(bs), "dtype": str(dtype)}
+            "NB": int(NB), "bs": int(bs), "dtype": str(dtype),
+            "G": head_group(Hkv, bs, Dh, dtype)}
 
 
 def candidates(shape: dict) -> list[dict]:
@@ -78,23 +95,25 @@ def cost(shape: dict, config: dict) -> KernelCost:
 
     # qk^T + pv over live keys only (early exit) for every query head
     flops = 4.0 * B * H * live * bs * Dh
-    # touched KV (live (bs, Dh) head tiles of the head-major pool, once
-    # per kv head via revisit elision) + q in / combined o out + f32
-    # split partials (acc, and m/l padded to a 128-lane row) written by
-    # the kernel and re-read by the combine + the int32 table/cache_len
+    # touched KV (live (G, bs, Dh) head-group blocks of the head-major
+    # pool, once per group via revisit elision) + q in / combined o out
+    # + f32 split partials (acc, and m/l padded to a 128-lane row)
+    # written by the kernel and re-read by the combine + the int32
+    # table/cache_len
     hbm = (bpe * 2.0 * B * Hkv * live * bs * Dh
            + bpe * 2.0 * B * H * Dh
            + 4.0 * 2.0 * B * H * ns * (Dh + 2 * 128)
            + 4.0 * (B * NB + B))
-    vmem = (bpe * (rep * Dh + 2 * bs * Dh)      # q block + k/v head tiles
-            + 4.0 * rep * Dh * 2                # f32 acc scratch + o partial
-            + 4.0 * rep * bkv                   # f32 score/prob chunk
-            + 4.0 * 2 * rep * 128 * 2)          # m/l lane-padded scratch + out
-    # Sequenced chain per (b, h): live grid steps (dead ones are clamped
-    # revisits — free) × loop trips; splits run on parallel cores.
+    G = head_group(Hkv, bs, Dh, shape["dtype"])
+    vmem = (bpe * G * (rep * Dh + 2 * 2 * bs * Dh)  # q + 2-buffered k/v blocks
+            + 4.0 * G * rep * Dh * 2            # f32 acc scratch + o partial
+            + 4.0 * G * rep * bkv               # f32 score/prob chunk
+            + 4.0 * 2 * G * rep * 128 * 2)      # m/l lane-padded scratch + out
+    # Sequenced chain per (b, head group): live blocks (dead ones are
+    # clamped revisits — free) × loop trips.
     npb = -(-NB // ns)
     live_steps = min(live, npb * ns)
-    n_steps = B * Hkv * live_steps * (1 + bs // bkv) / min(ns, _MEGACORE)
+    n_steps = B * (Hkv // G) * live_steps * (1 + bs // bkv)
     return KernelCost(
         op="paged_decode", op_class="matmul", origin="kernel",
         flops=flops, hbm_bytes=hbm, vmem_bytes=vmem,

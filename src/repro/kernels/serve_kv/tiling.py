@@ -76,7 +76,12 @@ def cost(shape: dict, config: dict) -> KernelCost:
     return KernelCost(
         op="serve_kv", op_class="matmul", origin="kernel",
         flops=pd.flops, hbm_bytes=hbm, vmem_bytes=pd.vmem_bytes,
-        n_steps=pd.n_steps,
+        # The pool block keeps the sequenced chain per KV head that the
+        # kernel had before a grid step read a block for G heads: a
+        # chain per head group ties 128 with 256 at 10 slots over 2048
+        # tokens and picks 128 at 32, which moves the pool, the tables
+        # and the chunk programs with it (docs/kernels.md "Flash-decode").
+        n_steps=pd.n_steps * pd_shape["G"],
         mxu_min_dim=pd.mxu_min_dim,
     )
 

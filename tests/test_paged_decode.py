@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.paged_decode import paged_decode_attention, paged_decode_ref
+from repro.kernels.paged_decode import tiling
 from repro.kernels.paged_decode.kernel import paged_decode_kernel
 from repro.models.layers import blocked_attention
 
@@ -61,6 +62,129 @@ def test_ref_kernel_gather_agree(dtype, H, Hkv, n_splits):
     np.testing.assert_allclose(np.asarray(gat, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
+
+
+def _assert_three_agree(q, kp, vp, bt, cl, dtype, **kw):
+    ref = paged_decode_ref(q, kp, vp, bt, cl)
+    ker = paged_decode_kernel(q, kp, vp, bt, cl, interpret=True, **kw)
+    gat = _gather_oracle(q, kp, vp, bt, cl)
+    tol = TOL[dtype]
+    for out in (ker, gat):
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_splits", [1, 2])
+def test_qwen3_head_layout_agree(dtype, n_splits):
+    """qwen3-4b's heads (32 query, 8 KV, head 128): one grid step reads a
+    block for all 8 KV heads.  Ragged fills: an idle row (0), bs-1, bs,
+    a row filling its table, and 0 again."""
+    bs, NB = 16, 4
+    assert tiling.head_group(8, bs, 128, dtype) == 8
+    q, kp, vp, bt, cl = _case(5, 32, 8, 128, NB, bs, dtype,
+                              [0, bs - 1, bs, NB * bs - 1, 0], seed=11)
+    _assert_three_agree(q, kp, vp, bt, cl, dtype, n_splits=n_splits)
+
+
+def test_head_group_smaller_than_kv_heads_agree():
+    """A pool block too large for every head in one step: f32 at bs 1024
+    and head 128 gives G = 4 of 8 KV heads, so the grid walks two head
+    groups, and both agree with the reference and the fallback."""
+    bs, NB = 1024, 2
+    assert tiling.head_group(8, bs, 128, "float32") == 4
+    q, kp, vp, bt, cl = _case(3, 16, 8, 128, NB, bs, "float32",
+                              [bs - 1, bs, 1500], seed=5)
+    _assert_three_agree(q, kp, vp, bt, cl, "float32", n_splits=2)
+
+
+_KV_BUDGET = 8 * 1024 * 1024     # half of the 16 MiB scoped VMEM
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("Hkv", [1, 4, 8, 32, 40])
+def test_head_group_divides_and_fits(Hkv, dtype):
+    bpe = {"bfloat16": 2, "float32": 4}[dtype]
+    for bs in (16, 32, 64, 128, 256, 512):
+        G = tiling.head_group(Hkv, bs, 128, dtype)
+        assert Hkv % G == 0
+        kv = 2 * 2 * G * bs * 128 * bpe        # K and V, double-buffered
+        assert kv <= _KV_BUDGET
+        # the largest such divisor: the next one up does not fit
+        bigger = [g for g in range(G + 1, Hkv + 1) if Hkv % g == 0]
+        if bigger:
+            assert 2 * 2 * bigger[0] * bs * 128 * bpe > _KV_BUDGET
+
+
+@pytest.mark.parametrize("bs", [128, 256, 512])
+def test_head_group_is_every_head_for_qwen3_4b(bs):
+    from repro.configs.registry import get_config
+
+    cfg = get_config("qwen3-4b")
+    assert tiling.head_group(cfg.n_kv_heads, bs, cfg.head_dim_,
+                             "bfloat16") == cfg.n_kv_heads == 8
+
+
+@pytest.mark.parametrize("Hkv,bs,dtype,n_splits,grid,steps", [
+    # 3 rows, table width 5: every KV head in one group, so one head
+    # group; blocks per split = ceil(5 / n_splits)
+    (8, 16, "bfloat16", 1, (3, 1, 1, 5), 15),
+    (8, 16, "float32", 2, (3, 1, 2, 3), 18),
+    # f32 at pool block 1024: two groups of 4 heads
+    (8, 1024, "float32", 2, (3, 2, 2, 3), 36),
+    (4, 256, "bfloat16", 4, (3, 1, 4, 2), 24)])
+def test_grid_is_rows_by_head_groups_by_splits_by_blocks(Hkv, bs, dtype,
+                                                         n_splits, grid,
+                                                         steps):
+    import jax
+
+    B, H, Dh, NB = 3, 2 * Hkv, 128, 5
+    S = jax.ShapeDtypeStruct
+    pool = S((B * NB + 1, Hkv, bs, Dh), dtype)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, bt, cl: paged_decode_kernel(
+        q, k, v, bt, cl, n_splits=n_splits, interpret=True))(
+        S((B, H, Dh), dtype), pool, pool, S((B, NB), "int32"),
+        S((B,), "int32")).jaxpr
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    got = eqn.params["grid_mapping"].grid
+    assert got == grid
+    assert math.prod(got) == steps
+
+
+@pytest.mark.parametrize("B", [10, 32])
+def test_cost_model_ranks_unsplit_full_block_first_for_qwen3_4b(B):
+    """At the qwen3-4b cells' shapes (10 and 32 rows, pool block 256, an
+    8-block table) the v5e model's first pick is block_kv 256 with no
+    split: on one v5e that tile ran fastest at both shapes and every
+    split count above 1 ran slower, so the timed shortlist must hold it."""
+    from repro.engine.devices import get_device
+    from repro.kernels.autotune import KernelTuner
+
+    tuner = KernelTuner(device=get_device("tpu_v5e"), cache=None,
+                        measure=False)
+    shape = tiling.shape_key(B, 32, 8, 128, 8, 256, "bfloat16")
+    assert tuner.tune("paged_decode", shape) == {"block_kv": 256,
+                                                 "n_splits": 1}
+
+
+def test_tuning_key_carries_head_group(tmp_path):
+    """Tiles cached under the one-head kernel's key (no G) are not
+    served to the head-group kernel: the lookup misses and retunes."""
+    from repro.engine.devices import get_device
+    from repro.kernels.autotune import KernelTuner, TuningCache
+
+    shape = tiling.shape_key(10, 32, 8, 128, 8, 256, "bfloat16")
+    assert shape["G"] == 8
+    old = {k: v for k, v in shape.items() if k != "G"}
+    cache = TuningCache(str(tmp_path / "t.json"))
+    tuner = KernelTuner(device=get_device("tpu_v5e"), cache=cache,
+                        measure=False)
+    cache.put(tuner.key("paged_decode", old),
+              {"config": {"block_kv": 16, "n_splits": 8}})
+    assert tuner.tune("paged_decode", shape) != {"block_kv": 16,
+                                                 "n_splits": 8}
+    assert (tuner.hits, tuner.misses) == (0, 1)
 
 
 @pytest.mark.parametrize("layer", [0, 1, 2])

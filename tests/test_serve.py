@@ -200,6 +200,17 @@ def test_serve_kv_block_size_resolved_through_tuning_cache(tmp_path):
     assert t3.misses == 1
 
 
+@pytest.mark.parametrize("n_slots", [10, 32])
+def test_serve_kv_block_size_for_qwen3_4b_cells_on_v5e(n_slots):
+    """The pool block the qwen3-4b cells run (10 and 32 slots over a
+    2048-token window) on the v5e spec: 256, priced through the
+    paged_decode cost model with a step chain per KV head."""
+    tuner = KernelTuner(device=get_device("tpu_v5e"), cache=None,
+                        measure=False)
+    assert resolve_block_size(get_config("qwen3-4b"), n_slots=n_slots,
+                              max_len=2048, tuner=tuner) == 256
+
+
 def test_paged_pool_allocator_and_footprint():
     cfg = _cfg()
     kv = PagedKVCache(cfg, n_slots=8, max_len=512, block_size=64)

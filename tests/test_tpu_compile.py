@@ -34,12 +34,16 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.mark.parametrize("B,bs,NB", [(8, 128, 16), (10, 256, 8),
+                                      (32, 256, 8)])
 @pytest.mark.parametrize("n_splits", [1, 2])
 @pytest.mark.parametrize("arch", ["internlm2-1.8b", "qwen3-4b"])
-def test_paged_decode_compiles_for_v5e(one_chip, arch, n_splits):
-    """Serving widths: 8 slots, pool block 128, a 2048-token table."""
+def test_paged_decode_compiles_for_v5e(one_chip, arch, n_splits, B, bs, NB):
+    """Serving widths over a 2048-token table: 8 slots at pool block
+    128, and the qwen3-4b cells' 10 (decode_long) and 32 (chat_poisson)
+    slots at pool block 256, where one grid step reads a block for all
+    KV heads."""
     cfg = get_config(arch)
-    B, bs, NB = 8, 128, 16
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     P = B * NB + 1
 
